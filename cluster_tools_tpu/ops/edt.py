@@ -16,7 +16,6 @@ min_j(f(j) + (i-j)²) is computed over all j.
 
 from __future__ import annotations
 
-import os
 from functools import partial
 from typing import Optional, Sequence, Tuple, Union
 
@@ -106,12 +105,10 @@ def _minplus_pallas_impl(flat: jnp.ndarray, spacing: float,
 
 
 def _use_pallas() -> bool:
-    """Pallas path on real TPUs; the XLA formulation elsewhere (Mosaic
-    does not target CPU, and interpret mode is debug-speed only).
-    ``CTT_EDT_PALLAS=0/1`` overrides."""
-    env = os.environ.get("CTT_EDT_PALLAS")
-    if env is not None:
-        return env == "1"
+    """Pallas path on TPUs, the only path there; the XLA formulation
+    elsewhere (Mosaic does not target CPU, and interpret mode is
+    debug-speed only).  Tests that need one formulation call ``_edt_impl``
+    or ``_minplus_pallas(..., interpret=True)`` directly."""
     return jax.default_backend() == "tpu"
 
 
@@ -170,11 +167,7 @@ def distance_transform_edt(
     by the reference for 2d-DT over anisotropic EM stacks).  ``axes``
     restricts the transform to a subset of axes — ``axes=(1, 2)`` on a 3d
     stack is the per-slice 2d EDT without any vmap (untransformed axes fold
-    into the scanline batch).
-
-    The kernel backend is chosen OUTSIDE the jit trace (the env override
-    ``CTT_EDT_PALLAS`` takes effect on the next call, not only the next
-    trace)."""
+    into the scanline batch)."""
     return _edt_impl(mask, sampling, tile, axes, _use_pallas())
 
 

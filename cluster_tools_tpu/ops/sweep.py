@@ -381,8 +381,8 @@ def rle_encode_packed(flat: jnp.ndarray, cap: int):
     # O(n) scatter pass instead of two (starts + values)
     m = jnp.where(brk, idx, jnp.uint32(n))
     # lax.cummin is the lowered scan primitive; associative_scan's
-    # recursive slicing formulation stalled the remote XLA compile at
-    # this length
+    # recursive slicing formulation stalled the XLA compile at this
+    # length
     nb = jax.lax.cummin(m, reverse=True)
     nb_next = jnp.concatenate([nb[1:], jnp.full((1,), n, jnp.uint32)])
     lengths = jnp.where(brk, nb_next - idx, 0)

@@ -37,10 +37,7 @@ def moe_apply(fn: Callable[[Any, jnp.ndarray], jnp.ndarray],
     ``g * expert(token) + (1 - g) * token`` for routed tokens (g = the
     gate's softmax weight of the chosen expert), identity for overflow.
     """
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     n_experts = mesh.shape[axis]
     t_local = tokens.shape[0] // n_experts

@@ -49,10 +49,7 @@ def pipeline_apply(fn: Callable[[Any, jnp.ndarray], jnp.ndarray],
     pipe axis at the end (all other stages contribute zeros), so the result
     is replicated — callers re-shard as needed.
     """
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     n_stages = mesh.shape[axis]
     n_micro = x.shape[0]

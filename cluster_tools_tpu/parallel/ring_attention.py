@@ -37,10 +37,7 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     ``(T, H, D)`` — ``softmax(q k^T / sqrt(D)) v`` computed without any
     device ever holding more than its ``T / n_shards`` slice of k/v.
     """
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     n_shards = mesh.shape[axis]
     t_local = q.shape[0] // n_shards

@@ -30,29 +30,10 @@ def _take(x: jnp.ndarray, axis: int, sl: slice) -> jnp.ndarray:
     return x[tuple(idx)]
 
 
-def axis_size(mesh_axis: str) -> int:
-    """STATIC size of a named mesh axis inside shard_map (the ppermute
-    ring and the edge-shard handling below need it as a Python int).
-    ``jax.lax.axis_size`` only exists in newer jax; fall back to the
-    tracing axis env."""
-    try:
-        return int(jax.lax.axis_size(mesh_axis))
-    except AttributeError:
-        from jax._src import core as _core
-
-        return int(_core.get_axis_env().axis_size(mesh_axis))
-
-
 def device_varying(a: jnp.ndarray, mesh_axis: str) -> jnp.ndarray:
-    """Mark ``a`` device-varying over ``mesh_axis`` inside shard_map —
-    the jax-version shim (pcast on current jax, pvary on the vma
-    transition releases, no-op on pre-vma jax where unmarked values are
-    already varying) shared by the ring collectives."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(a, (mesh_axis,), to="varying")
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(a, (mesh_axis,))
-    return a
+    """Mark ``a`` device-varying over ``mesh_axis`` inside shard_map (the
+    ring collectives' loop carries must start varying)."""
+    return jax.lax.pcast(a, (mesh_axis,), to="varying")
 
 
 def halo_exchange(x: jnp.ndarray, halo: int, axis: int, mesh_axis: str,
@@ -67,7 +48,7 @@ def halo_exchange(x: jnp.ndarray, halo: int, axis: int, mesh_axis: str,
     """
     if halo <= 0:
         return x
-    n = axis_size(mesh_axis)
+    n = jax.lax.axis_size(mesh_axis)
     idx = jax.lax.axis_index(mesh_axis)
 
     lo_slab = _take(x, axis, slice(0, halo))           # my low boundary
@@ -122,11 +103,6 @@ def sharded_stencil(fn, mesh: Mesh, halo: int, axis: int = 0,
     shard, and crops the halo back off — the single reusable primitive
     replacing the reference's outer/inner block machinery.
     """
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-
     def local(x):
         grown = halo_exchange(x, halo, axis, mesh_axis, fill=fill, mode=mode)
         out = fn(grown)
@@ -139,6 +115,7 @@ def sharded_stencil(fn, mesh: Mesh, halo: int, axis: int = 0,
 
     def apply(x):
         sp = specs(x.ndim)
-        return shard_map(local, mesh=mesh, in_specs=(sp,), out_specs=sp)(x)
+        return jax.shard_map(local, mesh=mesh, in_specs=(sp,),
+                             out_specs=sp)(x)
 
     return apply

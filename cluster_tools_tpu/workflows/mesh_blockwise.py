@@ -48,15 +48,6 @@ def _cc_round_program(n_dev: int, block_shape, connectivity: int):
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    try:
-        from jax import shard_map
-
-        _vma_kw = {"check_vma": False}
-    except ImportError:  # older jax: experimental home, check_rep kwarg
-        from jax.experimental.shard_map import shard_map
-
-        _vma_kw = {"check_rep": False}
-
     from ..ops.components import connected_components
     from ..parallel.mesh import blocks_mesh
 
@@ -89,11 +80,11 @@ def _cc_round_program(n_dev: int, block_shape, connectivity: int):
 
     # the CC while_loop carries per-device state; the varying-manual-axes
     # check cannot see through the data-dependent loop
-    fn = shard_map(
+    fn = jax.shard_map(
         per_device, mesh=mesh,
         in_specs=(spec,),
         out_specs=(spec, spec, spec, spec, spec),
-        **_vma_kw,
+        check_vma=False,
     )
     jitted = jax.jit(fn)
 

@@ -230,10 +230,7 @@ if __name__ == "__main__":
     from cluster_tools_tpu.parallel.multihost import (init_distributed,
                                                       make_multihost_mesh)
 
-    try:  # the version-compat import the library modules use
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     pid = int(sys.argv[1])
     init_distributed(coordinator_address="localhost:{port}",

@@ -324,41 +324,67 @@ def _trivial_compiled(mult: float = 3.0):
     return jax.jit(lambda x: x * mult).lower(jnp.ones((4,))).compile()
 
 
-def _needs_serialization():
-    if runtime._serialize_api() is None:
-        pytest.skip("this jax version cannot serialize AOT executables")
+def _one_device_compiled(dev_index: int):
+    """A one-device program committed to ``jax.devices()[dev_index]`` of
+    the 8-device virtual mesh — on a multi-device host, the disk tier must
+    load it back onto THAT device, not onto every device of the backend."""
+    import jax
+    import jax.numpy as jnp
+
+    x = jax.device_put(jnp.ones((4,)), jax.devices()[dev_index])
+    return jax.jit(lambda x: x * 3.0).lower(x).compile(), x
 
 
-def test_exec_cache_disk_roundtrip(exec_disk):
+def _mesh_compiled():
+    """A program sharded over 4 of the 8 virtual devices, mesh order
+    reversed (the recorded device order must survive the round trip)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(jax.devices()[:4][::-1]), ("d",))
+    x = jax.device_put(jnp.ones((8,)), NamedSharding(mesh, P("d")))
+    return jax.jit(lambda x: x * 3.0).lower(x).compile(), x
+
+
+@pytest.mark.parametrize("program", ["default", "device5", "mesh4"])
+def test_exec_cache_disk_roundtrip(exec_disk, program):
     """Cold: compile + persist.  After a process-death-equivalent memory
     clear, the same key deserializes from disk (no recompile) and the
-    loaded executable computes the same results."""
-    _needs_serialization()
-    key = ("triv", 3.0)
-    runtime.compile_cached(key, _trivial_compiled)
+    loaded executable computes the same results on the devices it was
+    compiled for."""
+    import jax.numpy as jnp
+
+    if program == "default":
+        build, x = _trivial_compiled, jnp.ones((4,))
+    else:
+        compiled, x = (_one_device_compiled(5) if program == "device5"
+                       else _mesh_compiled())
+        build = lambda: compiled  # noqa: E731
+    key = ("triv", program)
+    runtime.compile_cached(key, build)
     assert runtime.EXEC_CACHE_STATS["compiles"] == 1
     assert runtime.EXEC_CACHE_STATS["disk_writes"] == 1
     assert len(os.listdir(exec_disk)) == 1
 
     runtime.exec_cache_clear()     # memory only: the blob survives
     assert len(os.listdir(exec_disk)) == 1
-    ent = runtime.compile_cached(key, _trivial_compiled)
+    ent = runtime.compile_cached(key, build)
     assert runtime.EXEC_CACHE_STATS["compiles"] == 0
     assert runtime.EXEC_CACHE_STATS["disk_hits"] == 1
     assert runtime.EXEC_CACHE_STATS["deserialize_s"] > 0
-    import jax.numpy as jnp
-
-    np.testing.assert_array_equal(np.asarray(ent(jnp.ones((4,)))),
-                                  np.full(4, 3.0, "float32"))
+    out = ent(x)
+    assert out.sharding.device_set == x.sharding.device_set
+    np.testing.assert_array_equal(np.asarray(out),
+                                  np.full(x.shape, 3.0, "float32"))
     # memory hit on the NEXT lookup — disk is only the process boundary
-    runtime.compile_cached(key, _trivial_compiled)
+    runtime.compile_cached(key, build)
     assert runtime.EXEC_CACHE_STATS["hits"] == 1
 
 
 def test_exec_cache_corrupt_blob_recompiles(exec_disk):
     """A damaged blob must cost one recompile, never a crash; the bad
     file is dropped and replaced by the fresh executable."""
-    _needs_serialization()
     key = ("triv", 3.0)
     runtime.compile_cached(key, _trivial_compiled)
     blob = [f for f in os.listdir(exec_disk) if f.endswith(".jexec")][0]
@@ -378,7 +404,6 @@ def test_exec_cache_corrupt_blob_recompiles(exec_disk):
 def test_exec_cache_clear_disk(exec_disk):
     """exec_cache_clear(disk=True) purges the persisted tier AND resets
     the counters (satellite: the full cold-start reset)."""
-    _needs_serialization()
     runtime.compile_cached(("a",), _trivial_compiled)
     runtime.compile_cached(("b",), lambda: _trivial_compiled(5.0))
     assert len(os.listdir(exec_disk)) == 2
@@ -394,7 +419,6 @@ def test_exec_cache_clear_disk(exec_disk):
 
 def test_exec_cache_lru_eviction(exec_disk):
     """The disk tier is size-bounded: oldest-touched blobs evict first."""
-    _needs_serialization()
     runtime.compile_cached(("a",), _trivial_compiled)
     blob = os.path.join(exec_disk, os.listdir(exec_disk)[0])
     one = os.path.getsize(blob)
@@ -413,16 +437,21 @@ def test_exec_cache_lru_eviction(exec_disk):
     assert runtime.EXEC_CACHE_STATS["compiles"] == 1
 
 
-def test_exec_cache_fingerprint_binds_toolchain(exec_disk, monkeypatch):
-    """The digest covers (jax/jaxlib version, device topology): a version
-    bump means the old blob is simply never found — a MISS, not a load
-    of an incompatible executable."""
-    _needs_serialization()
+@pytest.mark.parametrize("change", ["toolchain", "source"])
+def test_exec_cache_fingerprint_binds_toolchain(exec_disk, monkeypatch,
+                                                change):
+    """The digest covers (jax/jaxlib version, device topology, package
+    source): a version bump or an edited program means the old blob is
+    simply never found — a MISS, not a load of a stale executable."""
     key = ("triv", 3.0)
     runtime.compile_cached(key, _trivial_compiled)
     runtime.exec_cache_clear()
-    monkeypatch.setattr(runtime, "_exec_cache_fingerprint",
-                        lambda: "jax-from-the-future")
+    if change == "toolchain":
+        monkeypatch.setattr(runtime, "_exec_cache_fingerprint",
+                            lambda: "jax-from-the-future")
+    else:
+        monkeypatch.setattr(runtime, "_package_source_digest",
+                            lambda: "edited")
     runtime.compile_cached(key, _trivial_compiled)
     assert runtime.EXEC_CACHE_STATS["disk_hits"] == 0
     assert runtime.EXEC_CACHE_STATS["compiles"] == 1
@@ -462,6 +491,32 @@ def test_global_config_activates_disk_tier(tmp_path):
         assert runtime._exec_cache_max_bytes() == 123456
     finally:
         runtime._DISK_TIER.update(saved)
+
+
+@pytest.mark.parametrize("env_set", [True, False])
+def test_use_compile_cache_places_both_tiers(tmp_path, monkeypatch,
+                                             env_set):
+    """Both cache tiers share one directory: JAX_COMPILATION_CACHE_DIR
+    when set, else the fixed <checkout>/.jax_cache.  Nothing is compiled
+    here, so no file lands in the checkout."""
+    import jax
+
+    if env_set:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        want = str(tmp_path)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), ".jax_cache")
+    saved = dict(runtime._DISK_TIER)
+    saved_jax = jax.config.jax_compilation_cache_dir
+    try:
+        assert runtime.use_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        assert runtime._exec_cache_dir() == want
+    finally:
+        runtime._DISK_TIER.update(saved)
+        jax.config.update("jax_compilation_cache_dir", saved_jax)
 
 
 # ---------------------------------------------------------------------------
@@ -512,7 +567,6 @@ def test_exec_cache_ledger_tracks_blob_bytes(exec_disk):
     """compile_cached accounts the serialized blob's size under the
     'exec_cache' ledger account — on the build path AND the disk-hit
     path — and exec_cache_clear releases it."""
-    _needs_serialization()
     runtime.ledger_clear()
     runtime.compile_cached(("triv", 3.0), _trivial_compiled)
     blob = [f for f in os.listdir(exec_disk) if f.endswith(".jexec")][0]

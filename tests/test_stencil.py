@@ -45,12 +45,7 @@ def _run_exchange(x, halo, axis, n_shards, mode="constant", fill=0):
     from cluster_tools_tpu.parallel.mesh import single_axis_mesh
     from cluster_tools_tpu.parallel.stencil import halo_exchange
 
-    try:
-        from jax import shard_map
-        kw = {"check_vma": False}
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-        kw = {"check_rep": False}
+    from jax import shard_map
 
     mesh = single_axis_mesh("space", n_shards)
     spec = [None] * x.ndim
@@ -61,7 +56,7 @@ def _run_exchange(x, halo, axis, n_shards, mode="constant", fill=0):
         return halo_exchange(s, halo, axis, "space", mode=mode, fill=fill)
 
     grown = shard_map(local, mesh=mesh, in_specs=(sp,), out_specs=sp,
-                      **kw)(jnp.asarray(x))
+                      check_vma=False)(jnp.asarray(x))
     # shard_map concatenates the per-shard outputs along the sharded axis
     return np.split(np.asarray(grown), n_shards, axis=axis)
 
