@@ -20,7 +20,7 @@ EXCLUDE_DIRS = frozenset({
 
 #: top-level driver scripts that carry lintable literals (metric names,
 #: config keys) but live outside the package directory
-TOP_LEVEL_SCRIPTS = ("bench.py", "bench_configs.py", "calibrate_fused.py")
+TOP_LEVEL_SCRIPTS = ("bench.py", "bench_configs.py")
 
 
 def package_root() -> str:

@@ -60,19 +60,12 @@ _BYTES_ACC: Dict[str, float] = {}
 _COUNT_ACC: Dict[str, int] = {}
 _STAGE_LOCK = threading.Lock()
 
-#: stage-name prefixes attributed to the ACCELERATOR PATH (device compute
-#: + host<->device transfers) when computing the
-#: per-task device_busy_frac in the status JSON — the chip-utilization
-#: observability the bench emits (VERDICT r4 item 8).  Device tasks split
-#: their program wait into ``sync-compile`` (one-time XLA builds) and
-#: ``sync-execute`` (steady-state waits): the two have 5x-different
-#: variance and lumping them made the bench headline a coin flip
-#: (BENCH_r05).  Host-side algorithm stages (union-find scans, table
-#: gathers) use ``host-`` names so they never inflate device_busy_frac.
-#: Canonical definition lives in core.telemetry so span-derived rollups
-#: and this accumulator can never disagree about what counts as device
-#: time.
-_DEVICE_STAGE_PREFIXES = telemetry.DEVICE_STAGE_PREFIXES
+# Stage names say what the host thread is doing, not what the device does:
+# device tasks split their program wait into ``sync-compile`` (one-time
+# XLA builds) and ``sync-execute`` (steady-state waits), host algorithms
+# use ``host-`` names, store IO ``store-``.  Device busy and idle time come
+# from the profiler's device trace, where each ``stage`` block also shows
+# as a ``ctt.stage.<name>`` span on the same clock.
 
 
 def stage_add(name: str, seconds: float, count: int = 1) -> None:
@@ -104,18 +97,26 @@ def bytes_delta(before: Dict[str, float]) -> Dict[str, float]:
 
 
 class stage:
-    """Context manager attributing elapsed wall time to a named stage."""
+    """Context manager attributing elapsed wall time to a named stage.
+    While a JAX profiler trace records, the block is also a
+    ``ctt.stage.<name>`` annotation in it, on the calling thread."""
+
+    __slots__ = ("name", "_t0", "_ann")
 
     def __init__(self, name: str):
         self.name = name
 
     def __enter__(self):
+        self._ann = telemetry.open_annotation("stage." + self.name)
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
+        seconds = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         # ctt-lint: disable=stage-registry (framework forwarder: the literal was already registry-checked at the stage(...) call site)
-        stage_add(self.name, time.perf_counter() - self._t0)
+        stage_add(self.name, seconds)
         return False
 
 
@@ -869,12 +870,18 @@ def prefetch_iter(items, load, window: int = 2):
     """Iterate ``load(item)`` results with a bounded thread-pool look-ahead
     (tensorstore/h5 reads release the GIL, so upcoming blocks load while
     the caller computes).  Yields in input order — the same bounded window
-    as :func:`stream_window`, with futures as the in-flight handles."""
+    as :func:`stream_window`, with futures as the in-flight handles.  The
+    consumer's wait for a load not yet done is the ``prefetch-wait``
+    stage; the load times its own stages, on the prefetch thread."""
     from concurrent.futures import ThreadPoolExecutor
+
+    def result(fut):
+        with stage("prefetch-wait"):
+            return fut.result()
 
     with ThreadPoolExecutor(max_workers=window) as pool:
         yield from stream_window(items, lambda it: pool.submit(load, it),
-                                 lambda fut: fut.result(), window=window)
+                                 result, window=window)
 
 
 class BoundedPool:
@@ -908,7 +915,7 @@ class BoundedPool:
         while len(self._pending) >= self.max_inflight:
             with witness_blocking("pool-result"):
                 self._pending.popleft().result()
-        if telemetry.enabled():
+        if telemetry.tracing():
             fn = self._traced(fn)
         self._pending.append(self._pool.submit(fn, *args, **kwargs))
 
@@ -1550,16 +1557,6 @@ class BlockTask(Task):
             for k, v in parse_stage_times(self.log_path(j),
                                           _COUNT_LINE).items():
                 stage_counts[k] = int(stage_counts.get(k, 0) + v)
-        # accelerator-path share of the task wall: device compute +
-        # host<->device transfers.  The
-        # complement is host compute + store IO + scheduling — where the
-        # chip idles (VERDICT r4: rounds were being steered blind here).
-        # Stages timed in overlapped pool workers use non-device names
-        # (fetch-*, host-*); the clamp below keeps the ratio meaningful
-        # even if overlapping device-prefixed stages ever double-count
-        device_time = sum(v for k, v in stages.items()
-                          if k.startswith(_DEVICE_STAGE_PREFIXES))
-        device_time = min(device_time, elapsed)
         status = {
             "task": self.name_with_id,
             "n_jobs": n_jobs,
@@ -1569,8 +1566,6 @@ class BlockTask(Task):
             "retries": self._retry_count,
             "stages": {k: round(v, 3) for k, v in sorted(
                 stages.items(), key=lambda kv: -kv[1])},
-            "device_busy_frac": (round(device_time / elapsed, 4)
-                                 if elapsed > 0 else None),
             "bytes_moved": {k: int(v) for k, v in sorted(
                 moved_bytes.items(), key=lambda kv: -kv[1])},
             # how many times each stage was entered: the dispatch-model

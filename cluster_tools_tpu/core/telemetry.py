@@ -12,13 +12,19 @@ where the pipeline bubbles are.  This module adds the missing timeline:
   per-thread span stack; monotonic start/end timestamps; thread, tenant
   and request attributes; bounded ring buffer so an always-on service
   cannot grow trace state forever);
+* a second sink, the JAX profiler: while a ``jax.profiler`` trace
+  records, every ``runtime.stage(...)`` block and every :func:`span`
+  also opens a ``jax.profiler.TraceAnnotation`` on the thread that does
+  the work (``ctt.stage.<stage>``, ``ctt.<cat>.<name>``), so the
+  program's spans sit on the device trace's clock beside the device
+  ops.  The sink needs no configuration: with the profiler off it costs
+  one ``TraceAnnotation.is_enabled()`` check per span boundary;
 * a Chrome trace-event JSON exporter (:func:`export_chrome_trace`) —
   the output loads directly in Perfetto / chrome://tracing (same event
   shape as ``jax.profiler``'s trace dumps);
-* span-derived rollups — device-busy seconds/fraction (cross-checkable
-  against the ``device_busy_frac`` accumulator in task status JSONs),
-  pipeline-bubble fraction (the fraction of the trace window where NO
-  device-path stage is active), and queue-wait histograms;
+* span-derived rollups — per-stage seconds and entries, queue-wait
+  histograms and the memory rollup (device busy and idle time come
+  from the profiler's device trace, not from host spans);
 * a Prometheus-text-format snapshot writer (:func:`write_prometheus`)
   used by the resident server's ``metrics.prom`` and by the per-task
   ``metrics_path`` global-config hook.
@@ -26,7 +32,8 @@ where the pipeline bubbles are.  This module adds the missing timeline:
 Design constraints:
 
 * **Telemetry off must be free.**  Every instrumentation site guards on
-  :func:`enabled` (one attribute read); ``bench.py trace`` gates the
+  :func:`enabled` (one attribute read) and, for the profiler sink,
+  :func:`profiling` (one ``is_enabled`` call); ``bench.py trace`` gates the
   projected telemetry-off overhead at <1% of the flagship wall, and the
   tier-1 suite re-checks the per-call bound against the committed
   TRACE artifact.
@@ -47,6 +54,7 @@ import itertools
 import json
 import os
 import re
+import sys
 import threading
 import time
 from collections import Counter, deque
@@ -57,14 +65,6 @@ from typing import Any, Callable, Dict, Iterable, List, NamedTuple, \
 # canonical stage-name registry
 # ---------------------------------------------------------------------------
 
-#: stage-name prefixes attributed to the ACCELERATOR PATH (device compute
-#: + host<->device transfers).  Shared with
-#: core/runtime.py's ``device_busy_frac`` accounting — ONE definition, so
-#: the span-derived rollups and the accumulator can never disagree about
-#: what counts as device time.
-DEVICE_STAGE_PREFIXES = ("sync-", "d2h-", "h2d-", "dispatch", "cap-retry",
-                         "device-")
-
 #: every stage name the package may pass to ``runtime.stage`` /
 #: ``stage_add`` / ``stage_bytes``.  A typo'd literal would silently open
 #: a new bucket in ``stage_counts`` (and vanish from dashboards keyed on
@@ -72,23 +72,26 @@ DEVICE_STAGE_PREFIXES = ("sync-", "d2h-", "h2d-", "dispatch", "cap-retry",
 #: stage literals and fails on any name missing here.  Extensions
 #: register theirs via :func:`register_stage`.
 STAGE_REGISTRY = {
-    # device path (see DEVICE_STAGE_PREFIXES)
+    # host waits on and transfers to the device path
     "sync-compile",     # one-time XLA builds (AOT lower().compile())
     "sync-execute",     # steady-state waits on device programs
     "dispatch",         # program enqueue (async dispatch)
     "cap-retry",        # capacity-overflow redo through the big program
     "h2d-upload",       # host -> device volume uploads
     "d2h-dense", "d2h-edges", "d2h-labels", "d2h-rle",  # device -> host
-    # host path (never counts toward device_busy_frac)
+    # host compute
     "host-decode", "host-fallback", "host-map", "host-reduce",
     "host-scan", "host-solve",
     # count only: mesh_resident requested, streamed per-block path taken
     "mesh-fallback",
-    # pool-worker fetches (overlapped with sync-execute; fetch- not d2h-
-    # so the link is not double-counted into device_busy_frac)
+    # pool-worker fetches, overlapped with the main thread's sync-execute
+    # waits (fetch- rather than d2h-: the copies were started at submit)
     "fetch-dense", "fetch-rle",
     # store IO
     "store-read", "store-write",
+    # the consumer's wait for a read that a prefetch thread runs (the
+    # read itself is that thread's store-read: not counted twice)
+    "prefetch-wait",
     # interactive proofreading lanes (edits/ subsystem)
     "edit:resolve", "edit:solve", "edit:patch", "edit:write",
 }
@@ -202,6 +205,76 @@ def enabled() -> bool:
 def now() -> float:
     """The recorder's clock (injectable via :func:`configure`)."""
     return _REC.clock()
+
+
+# ---------------------------------------------------------------------------
+# profiler sink: program spans in a JAX profiler trace
+# ---------------------------------------------------------------------------
+
+#: name prefix of every program span in a profiler trace:
+#: ``ctt.stage.<stage>`` for ``runtime.stage`` blocks, ``ctt.<cat>.<name>``
+#: for :func:`span` (attempt, job, block, pool, ...)
+PROFILER_PREFIX = "ctt."
+
+_ANNOTATION = None      # jax.profiler.TraceAnnotation, once jax is imported
+
+
+def _annotation_type():
+    """``jax.profiler.TraceAnnotation``, or None while jax is not imported
+    (no profiler can be recording then; telemetry never imports jax)."""
+    global _ANNOTATION
+    if _ANNOTATION is None and "jax" in sys.modules:
+        try:
+            from jax.profiler import TraceAnnotation
+        except Exception:   # jax half-imported on another thread
+            return None
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
+
+
+def profiling() -> bool:
+    """True while a JAX profiler trace records in this process."""
+    ann = _ANNOTATION or _annotation_type()
+    return ann is not None and ann.is_enabled()
+
+
+def tracing() -> bool:
+    """True when either sink records: the span ring or a profiler trace."""
+    return _REC.enabled or profiling()
+
+
+def open_annotation(name: str):
+    """Enter a profiler ``TraceAnnotation`` called :data:`PROFILER_PREFIX`
+    + ``name`` and return it, or return None when no profiler trace
+    records.  The caller exits it, on the same thread."""
+    ann = _ANNOTATION or _annotation_type()
+    if ann is None or not ann.is_enabled():
+        return None
+    a = ann(PROFILER_PREFIX + name)
+    a.__enter__()
+    return a
+
+
+class _ProfilerSpan:
+    """A :func:`span` while only the profiler sink records: the
+    annotation, and no ring entry."""
+
+    __slots__ = ("name", "_ann")
+
+    def __init__(self, name: str):
+        self.name = name
+
+    def __enter__(self):
+        self._ann = open_annotation(self.name)
+        return self
+
+    def __exit__(self, *exc):
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        return False
+
+    def annotate(self, **attrs):
+        """No-op twin of :meth:`_SpanCtx.annotate`."""
 
 
 def configure(enabled: Optional[bool] = None,
@@ -325,7 +398,7 @@ _NULL_SPAN = _NullSpan()
 
 
 class _SpanCtx:
-    __slots__ = ("name", "cat", "attrs", "sid", "parent", "_t0")
+    __slots__ = ("name", "cat", "attrs", "sid", "parent", "_t0", "_ann")
 
     def __init__(self, name: str, cat: str, attrs: Dict[str, Any]):
         self.name, self.cat, self.attrs = name, cat, attrs
@@ -336,11 +409,14 @@ class _SpanCtx:
         with _REC.lock:
             self.sid = next(_REC._next_sid)
         stack.append(self.sid)
+        self._ann = open_annotation(f"{self.cat}.{self.name}")
         self._t0 = _REC.clock()
         return self
 
     def __exit__(self, *exc):
         t1 = _REC.clock()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         stack = _REC.stack()
         if stack and stack[-1] == self.sid:
             stack.pop()
@@ -363,9 +439,13 @@ class _SpanCtx:
 def span(name: str, cat: str = "stage", **attrs):
     """Context manager opening a span; children recorded on the same
     thread (nested ``span``s, ``runtime.stage`` blocks, ``record`` calls)
-    link to it as their parent.  When disabled, returns a shared no-op
-    context — the instrumentation site pays one attribute read."""
+    link to it as their parent.  While a profiler trace records, the
+    span is also a ``ctt.<cat>.<name>`` annotation in it.  When neither
+    sink records, returns a shared no-op context — the instrumentation
+    site pays one attribute read and one ``is_enabled`` check."""
     if not _REC.enabled:
+        if profiling():
+            return _ProfilerSpan(f"{cat}.{name}")
         return _NULL_SPAN
     return _SpanCtx(name, cat, attrs)
 
@@ -613,77 +693,6 @@ def export_chrome_trace(path: str,
 # span-derived rollups
 # ---------------------------------------------------------------------------
 
-def _merge_intervals(iv: List[Tuple[float, float]]
-                     ) -> List[Tuple[float, float]]:
-    """Union-merge of (start, end) intervals (sorted output)."""
-    out: List[Tuple[float, float]] = []
-    for t0, t1 in sorted(iv):
-        if out and t0 <= out[-1][1]:
-            if t1 > out[-1][1]:
-                out[-1] = (out[-1][0], t1)
-        else:
-            out.append((t0, t1))
-    return out
-
-
-def _device_stage_spans(spans: Sequence[Span]) -> List[Span]:
-    return [s for s in spans if s.cat == "stage"
-            and s.name.startswith(DEVICE_STAGE_PREFIXES)]
-
-
-def device_busy_seconds(spans: Optional[Sequence[Span]] = None) -> float:
-    """SUM of device-path stage span durations — the same semantics as
-    the ``device_busy_frac`` accumulator in task status JSONs (sum of
-    device-prefixed stage seconds), so the two cross-check directly."""
-    if spans is None:
-        spans = spans_snapshot()
-    return float(sum(s.t1 - s.t0 for s in _device_stage_spans(spans)))
-
-
-def busy_timeline(spans: Optional[Sequence[Span]] = None,
-                  prefixes: Tuple[str, ...] = DEVICE_STAGE_PREFIXES
-                  ) -> List[Tuple[float, float]]:
-    """Union-merged (start, end) intervals where at least one stage with
-    a matching prefix was active — the device-busy timeline, as the
-    host's stage spans see it (not a device trace).  Callers with
-    multi-device spans can filter by a ``device`` attr before merging."""
-    if spans is None:
-        spans = spans_snapshot()
-    return _merge_intervals(
-        [(s.t0, s.t1) for s in spans if s.cat == "stage"
-         and s.name.startswith(prefixes)])
-
-
-def device_busy_fraction(wall: Optional[float] = None,
-                         spans: Optional[Sequence[Span]] = None
-                         ) -> Optional[float]:
-    """Device-busy seconds / wall (clamped to 1.0, like the accumulator).
-    ``wall`` defaults to the trace window (earliest t0 to latest t1)."""
-    if spans is None:
-        spans = spans_snapshot()
-    if wall is None:
-        wall = trace_window(spans)
-    if not wall:
-        return None
-    return min(device_busy_seconds(spans) / wall, 1.0)
-
-
-def pipeline_bubble_fraction(spans: Optional[Sequence[Span]] = None,
-                             wall: Optional[float] = None
-                             ) -> Optional[float]:
-    """Fraction of the trace window where NO device-path stage was
-    active — the pipeline-bubble metric ROADMAP item 1 steers on.  Uses
-    the union-merged timeline (overlapping stages don't double-count)."""
-    if spans is None:
-        spans = spans_snapshot()
-    if wall is None:
-        wall = trace_window(spans)
-    if not wall:
-        return None
-    covered = sum(t1 - t0 for t0, t1 in busy_timeline(spans))
-    return max(1.0 - covered / wall, 0.0)
-
-
 def trace_window(spans: Optional[Sequence[Span]] = None) -> float:
     if spans is None:
         spans = spans_snapshot()
@@ -793,8 +802,6 @@ def rollup_spans(spans: Sequence[Span], wall: Optional[float] = None,
             + (s.t1 - s.t0)
         stage_entries[s.name] = stage_entries.get(s.name, 0) \
             + int(s.attrs.get("count", 1))
-    busy = device_busy_seconds(spans)
-    merged = sum(t1 - t0 for t0, t1 in busy_timeline(spans))
     return {
         "n_spans": len(spans),
         "dropped": dropped,
@@ -805,12 +812,6 @@ def rollup_spans(spans: Sequence[Span], wall: Optional[float] = None,
             stage_seconds.items(), key=lambda kv: -kv[1])},
         "stage_entries": dict(sorted(stage_entries.items(),
                                      key=lambda kv: -kv[1])),
-        "device_busy_s": round(busy, 4),
-        "device_busy_timeline_s": round(merged, 4),
-        "device_busy_frac": (round(min(busy / wall, 1.0), 4)
-                             if wall else None),
-        "pipeline_bubble_frac": (round(max(1.0 - merged / wall, 0.0), 4)
-                                 if wall else None),
         "queue_wait": queue_wait_histogram(spans=spans),
         "memory": memory_rollup(spans),
     }
@@ -818,10 +819,9 @@ def rollup_spans(spans: Sequence[Span], wall: Optional[float] = None,
 
 def summary(wall: Optional[float] = None) -> Dict[str, Any]:
     """One-call rollup of the recorded trace: span counts by category,
-    per-stage second sums, device-busy (sum AND merged-timeline views),
-    bubble fraction, queue-wait histogram, memory rollup, ring drops.
-    ``wall`` (e.g. the measured workflow wall) scopes the busy fraction;
-    defaults to the trace window."""
+    per-stage second sums and entries, queue-wait histogram, memory
+    rollup, ring drops.  ``wall`` (e.g. the measured workflow wall) is
+    reported beside the trace window; defaults to it."""
     return rollup_spans(spans_snapshot(), wall=wall,
                         dropped=dropped_count())
 
@@ -892,8 +892,8 @@ def merge_chrome_traces(shard_paths: Sequence[str], out_path: str,
     Process ``i`` becomes Perfetto pid ``process_index + 1`` (the
     single-process exporter's pinned ``pid=1`` collides across shards).
     The merged span list feeds the SAME rollups as a single-process
-    trace, so ``device_busy_s``/bubble fraction aggregate across the
-    mesh; per-process ``device_busy_s`` is returned for cross-checks."""
+    trace, so stage seconds aggregate across the mesh; per-process span
+    counts are returned for cross-checks."""
     shards = [load_trace_shard(p) for p in shard_paths]
     if not shards:
         raise ValueError("merge_chrome_traces: no shards")
@@ -923,7 +923,6 @@ def merge_chrome_traces(shard_paths: Sequence[str], out_path: str,
             "pid": pid,
             "n_spans": len(spans),
             "dropped": int(sh.get("dropped", 0)),
-            "device_busy_s": round(device_busy_seconds(spans), 4),
             "clock_offset_s": round(
                 float(sh.get("wall_anchor", 0.0)) - wall0, 6),
         })
@@ -1056,18 +1055,23 @@ def histogram_family(name: str, help_text: str,
 # trace-diff regression gate (rollup-vs-rollup comparison)
 # ---------------------------------------------------------------------------
 
+#: stages whose regressions GATE in :func:`diff_rollups`: the host's
+#: waits on device programs and its transfers to and from the device.
+#: Every other stage (host compute, store IO) only warns.
+_GATED_STAGE_PREFIXES = ("sync-", "d2h-", "h2d-", "dispatch", "cap-retry",
+                         "device-")
+
+
 def diff_rollups(a: Dict[str, Any], b: Dict[str, Any], *,
                  rel_threshold: float = 0.2, abs_floor_s: float = 0.05,
-                 bubble_abs: float = 0.05,
                  mem_abs_floor_gb: float = 0.25) -> Dict[str, Any]:
     """Compare two span rollups (``summary()`` dicts, or the ``rollups``
-    section of a TRACE artifact): per-stage seconds, total device-busy
-    seconds, the pipeline-bubble fraction, and the memory peaks.
+    section of a TRACE artifact): per-stage seconds and the memory peaks.
 
     A quantity REGRESSES when the candidate ``b`` exceeds the baseline
     ``a`` by more than ``max(abs_floor_s, rel_threshold * a)`` (the abs
     floor keeps microsecond stages from tripping the relative gate on
-    noise).  Device-path stages, the device-busy total, and the memory
+    noise).  Device-path stages (``_GATED_STAGE_PREFIXES``) and the memory
     peaks (``peak_host_rss_gb``/``peak_device_gb``, against
     ``max(mem_abs_floor_gb, rel_threshold * a)``) GATE; host/store stage
     regressions are reported as warnings only, because host time is the
@@ -1095,7 +1099,7 @@ def diff_rollups(a: Dict[str, Any], b: Dict[str, Any], *,
         av, bv = _stage_val(sa, name), _stage_val(sb, name)
         delta = bv - av
         worse = delta > max(abs_floor_s, rel_threshold * av)
-        device = name.startswith(DEVICE_STAGE_PREFIXES)
+        device = name.startswith(_GATED_STAGE_PREFIXES)
         stages[name] = {
             "a_s": round(av, 4), "b_s": round(bv, 4),
             "delta_s": round(delta, 4),
@@ -1104,26 +1108,6 @@ def diff_rollups(a: Dict[str, Any], b: Dict[str, Any], *,
         }
         if worse:
             (regressions if device else warnings).append(f"stage:{name}")
-    def _num(doc, key, default=None):
-        try:
-            v = doc.get(key, default)
-            return default if v is None else float(v)
-        except (TypeError, ValueError):
-            return default
-
-    busy_a = _num(a, "device_busy_s", 0.0)
-    busy_b = _num(b, "device_busy_s", 0.0)
-    busy_delta = busy_b - busy_a
-    busy_worse = busy_delta > max(abs_floor_s, rel_threshold * busy_a)
-    if busy_worse:
-        regressions.append("device_busy_s")
-    bub_a = _num(a, "pipeline_bubble_frac")
-    bub_b = _num(b, "pipeline_bubble_frac")
-    bub_delta = (None if bub_a is None or bub_b is None
-                 else bub_b - bub_a)
-    bub_worse = bub_delta is not None and bub_delta > bubble_abs
-    if bub_worse:
-        regressions.append("pipeline_bubble_frac")
     ma = a.get("memory")
     mb = b.get("memory")
     if not isinstance(ma, dict):
@@ -1152,16 +1136,8 @@ def diff_rollups(a: Dict[str, Any], b: Dict[str, Any], *,
             regressions.append(f"memory:{key}")
     return {
         "thresholds": {"rel": rel_threshold, "abs_floor_s": abs_floor_s,
-                       "bubble_abs": bubble_abs,
                        "mem_abs_floor_gb": mem_abs_floor_gb},
         "stages": stages,
-        "device_busy": {"a_s": round(busy_a, 4), "b_s": round(busy_b, 4),
-                        "delta_s": round(busy_delta, 4),
-                        "regressed": busy_worse},
-        "bubble": {"a": bub_a, "b": bub_b,
-                   "delta": (round(bub_delta, 4)
-                             if bub_delta is not None else None),
-                   "regressed": bub_worse},
         "memory": memory,
         "regressions": regressions,
         "warnings": warnings,

@@ -100,6 +100,7 @@ def _minplus_pallas_impl(flat: jnp.ndarray, spacing: float,
         out_specs=pl.BlockSpec((_TM, ti), lambda mi, ii, ji: (mi, ii)),
         out_shape=jax.ShapeDtypeStruct((m_pad, n_128), jnp.float32),
         interpret=interpret,
+        name="edt_min_plus",
     )(f)
     return out[:m, :n]
 

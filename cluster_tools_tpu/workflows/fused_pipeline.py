@@ -67,7 +67,6 @@ from __future__ import annotations
 
 import json
 import os
-import time
 from functools import lru_cache
 from typing import Any, Dict, Optional
 
@@ -360,20 +359,26 @@ def _resident_program(outer_shape, halo, in_dtype, threshold: float,
             vol, tuple(origin[d] for d in range(len(outer_shape))),
             outer_shape)
         xf = x.astype(jnp.float32) * (1.0 / 255.0) if is_u8 else x
-        fg = xf < threshold
-        dt = distance_transform_edt(fg)
-        height = alpha * (gaussian(xf, sigma_weights) if sigma_weights
-                          else xf) + (1.0 - alpha) * (
-            1.0 - dt / jnp.maximum(dt.max(), 1e-6))
-        dt_smooth = gaussian(dt, sigma_seeds) if sigma_seeds else dt
-        maxima = local_maxima(dt_smooth, radius=2) & fg
-        seeds = connected_components(maxima, connectivity=3,
-                                     method="propagation")
+        # each stage under a named scope: the scope is the op_name prefix
+        # of its device ops, which the profiler trace reports per op
+        with jax.named_scope("edt"):
+            fg = xf < threshold
+            dt = distance_transform_edt(fg)
+        with jax.named_scope("smooth"):
+            height = alpha * (gaussian(xf, sigma_weights) if sigma_weights
+                              else xf) + (1.0 - alpha) * (
+                1.0 - dt / jnp.maximum(dt.max(), 1e-6))
+            dt_smooth = gaussian(dt, sigma_seeds) if sigma_seeds else dt
+        with jax.named_scope("seeds"):
+            maxima = local_maxima(dt_smooth, radius=2) & fg
+            seeds = connected_components(maxima, connectivity=3,
+                                         method="propagation")
         # SHARED watershed core: the classic Watershed task's device path
         # runs the identical composition, so fused and classic chains
         # produce the same fragment partition
-        ws, ok = _coarse_impl(height, seeds, min_size, refine_rounds,
-                              coarse_factor, dense_ids=True)
+        with jax.named_scope("watershed"):
+            ws, ok = _coarse_impl(height, seeds, min_size, refine_rounds,
+                                  coarse_factor, dense_ids=True)
 
         # dense per-block relabel of the INNER region; ``extent`` is the
         # REAL (clipped) inner size of border blocks — the reflect-padded
@@ -383,10 +388,11 @@ def _resident_program(outer_shape, halo, in_dtype, threshold: float,
         # presence table is coarse-voxel-sized, not outer-voxel-sized
         cn_bound = int(np.prod([-(-o // coarse_factor)
                                 for o in outer_shape]))
-        inner = ws[inner_sl]
-        valid = extent_valid_mask(inner.shape, extent=extent)
-        dense_grid, k = dense_relabel(inner, cn_bound, valid=valid)
-        dense = dense_grid.reshape(-1)
+        with jax.named_scope("relabel"):
+            inner = ws[inner_sl]
+            valid = extent_valid_mask(inner.shape, extent=extent)
+            dense_grid, k = dense_relabel(inner, cn_bound, valid=valid)
+            dense = dense_grid.reshape(-1)
 
         if is_u8:
             # uint8 inputs keep their RAW byte samples through the stats
@@ -399,61 +405,67 @@ def _resident_program(outer_shape, halo, in_dtype, threshold: float,
             # Packing needs every dense label < 2^15: any block that
             # dense would overflow e_max anyway, and the guard below
             # routes it to the host fallback via the ok flag
-            u, v, va, vb, okp = boundary_pair_values_dual(dense_grid,
-                                                          x[inner_sl])
-            n = int(u.shape[0])
-            # pair_cap IS the capacity (clamped to the pair-array length,
-            # past which no demand exists) — the retry program's raised
-            # pair_cap must raise the real cap, so no heuristic may bind
-            # tighter here
-            cap = max(min(pair_cap, 1 << int(np.ceil(np.log2(max(
-                n, 2))))), 1 << 13)
-            key = u * 32768 + v
-            vab = va.astype(jnp.int32) * 256 + vb.astype(jnp.int32)
-            (ckey, cvab), cok, cap_overflow = compact_valid(
-                okp, [key, vab], cap)
-            uv, feats, n_runs, e_overflow = _edge_stats_hist_packed(
-                ckey, cvab, cok, e_max=e_max)
+            with jax.named_scope("pairs"):
+                u, v, va, vb, okp = boundary_pair_values_dual(dense_grid,
+                                                              x[inner_sl])
+                n = int(u.shape[0])
+                # pair_cap IS the capacity (clamped to the pair-array
+                # length, past which no demand exists) — the retry
+                # program's raised pair_cap must raise the real cap, so
+                # no heuristic may bind tighter here
+                cap = max(min(pair_cap, 1 << int(np.ceil(np.log2(max(
+                    n, 2))))), 1 << 13)
+                key = u * 32768 + v
+                vab = va.astype(jnp.int32) * 256 + vb.astype(jnp.int32)
+                (ckey, cvab), cok, cap_overflow = compact_valid(
+                    okp, [key, vab], cap)
+            with jax.named_scope("edge_stats"):
+                uv, feats, n_runs, e_overflow = _edge_stats_hist_packed(
+                    ckey, cvab, cok, e_max=e_max)
             ok = ok & (k < (1 << 15))
         else:  # float inputs: the full sorted-position path
-            u, v, vals, okp = boundary_pair_values(dense_grid,
-                                                   xf[inner_sl])
-            n = int(u.shape[0])
-            # pair_cap is PAIR-denominated; this path carries two
-            # samples per pair.  As above, the (clamped) pair_cap is the
-            # capacity so the retry's raised cap takes effect
-            cap = max(min(2 * pair_cap, 1 << int(np.ceil(np.log2(max(
-                n, 2))))), 1 << 14)
-            (cu, cv, cvals), cok, cap_overflow = compact_valid(
-                okp, [u, v, vals], cap)
-            uv, feats, n_runs, e_overflow = _edge_stats_device(
-                cu, cv, cvals, cok, e_max=e_max)
+            with jax.named_scope("pairs"):
+                u, v, vals, okp = boundary_pair_values(dense_grid,
+                                                       xf[inner_sl])
+                n = int(u.shape[0])
+                # pair_cap is PAIR-denominated; this path carries two
+                # samples per pair.  As above, the (clamped) pair_cap is
+                # the capacity so the retry's raised cap takes effect
+                cap = max(min(2 * pair_cap, 1 << int(np.ceil(np.log2(max(
+                    n, 2))))), 1 << 14)
+                (cu, cv, cvals), cok, cap_overflow = compact_valid(
+                    okp, [u, v, vals], cap)
+            with jax.named_scope("edge_stats"):
+                uv, feats, n_runs, e_overflow = _edge_stats_device(
+                    cu, cv, cvals, cok, e_max=e_max)
 
-        packed, n_rle, rle_ok = rle_encode_packed(dense, rle_cap)
-        meta = jnp.stack([
-            k, n_runs, e_overflow, cap_overflow,
-            ok.astype(jnp.int32), n_rle, rle_ok.astype(jnp.int32)])
-        # ONE combined meta+uv+feats float32 table per block: row 0 is
-        # the meta vector, rows 1.. are [u, v, feats...].  Every value is
-        # exactly representable in f32 (ids < 2^15, counts < 2^24;
-        # overflow counters are only >0 tests) and the drain pays a
-        # single device-to-host round trip instead of three (meta sync
-        # + uv + feats)
-        body = jnp.concatenate(
-            [uv.astype(jnp.float32), feats.astype(jnp.float32)], axis=1)
-        meta_row = jnp.concatenate(
-            [meta.astype(jnp.float32),
-             jnp.zeros((body.shape[1] - meta.shape[0],),
-                       jnp.float32)])[None, :]
-        tbl = jnp.concatenate([meta_row, body], axis=0)
-        # static halves: the drain fetches the low half always and the
-        # high half only when the run count spills into it — plain
-        # buffer transfers, never a device-side slicing program that
-        # would queue behind in-flight block programs
-        packed_lo = packed[:rle_cap // 2]
-        packed_hi = packed[rle_cap // 2:]
-        return (tbl, packed_lo, packed_hi,
-                dense_grid.astype(jnp.uint16), dense_grid)
+        with jax.named_scope("rle"):
+            packed, n_rle, rle_ok = rle_encode_packed(dense, rle_cap)
+            meta = jnp.stack([
+                k, n_runs, e_overflow, cap_overflow,
+                ok.astype(jnp.int32), n_rle, rle_ok.astype(jnp.int32)])
+            # ONE combined meta+uv+feats float32 table per block: row 0
+            # is the meta vector, rows 1.. are [u, v, feats...].  Every
+            # value is exactly representable in f32 (ids < 2^15, counts
+            # < 2^24; overflow counters are only >0 tests) and the drain
+            # pays a single device-to-host round trip instead of three
+            # (meta sync + uv + feats)
+            body = jnp.concatenate(
+                [uv.astype(jnp.float32), feats.astype(jnp.float32)],
+                axis=1)
+            meta_row = jnp.concatenate(
+                [meta.astype(jnp.float32),
+                 jnp.zeros((body.shape[1] - meta.shape[0],),
+                           jnp.float32)])[None, :]
+            tbl = jnp.concatenate([meta_row, body], axis=0)
+            # static halves: the drain fetches the low half always and
+            # the high half only when the run count spills into it —
+            # plain buffer transfers, never a device-side slicing program
+            # that would queue behind in-flight block programs
+            packed_lo = packed[:rle_cap // 2]
+            packed_hi = packed[rle_cap // 2:]
+            dense16 = dense_grid.astype(jnp.uint16)
+        return tbl, packed_lo, packed_hi, dense16, dense_grid
 
     if batched:
         # mesh rounds: one block per device — the volume is replicated,
@@ -909,8 +921,8 @@ class FusedSegmentationBlocks(BlockTask):
         import jax.numpy as jnp
 
         from ..core import telemetry
-        from ..core.runtime import (stage, stage_add, stage_bytes,
-                                    stream_window, writer_pool)
+        from ..core.runtime import (stage, stage_bytes, stream_window,
+                                    writer_pool)
         from ..ops.sweep import rle_decode_packed
         from .watershed import _normalize_input
 
@@ -945,9 +957,10 @@ class FusedSegmentationBlocks(BlockTask):
         # grid-aligned + halo padding by VOLUME-level reflection — the
         # same fold every per-block reader uses (read_outer_reflect), so
         # resident slices match per-block store reads exactly
-        volp = vol[np.ix_(*[
-            reflect_indices(-h, g * b + h, s)
-            for h, g, b, s in zip(halo, gdims, bs, shape)])]
+        with stage("host-map"):
+            volp = vol[np.ix_(*[
+                reflect_indices(-h, g * b + h, s)
+                for h, g, b, s in zip(halo, gdims, bs, shape)])]
         with stage("h2d-upload"):
             vol_dev = jnp.asarray(volp)
         stage_bytes("h2d-upload", volp.nbytes)
@@ -967,9 +980,8 @@ class FusedSegmentationBlocks(BlockTask):
                         cfg["output_key"])
 
         def _write(bb, arr):
-            t0 = time.perf_counter()
-            ds_out[bb] = arr
-            stage_add("store-write", time.perf_counter() - t0)
+            with stage("store-write"):
+                ds_out[bb] = arr
             stage_bytes("store-write", arr.nbytes)
 
         def _origin_extent(block):
@@ -1023,9 +1035,8 @@ class FusedSegmentationBlocks(BlockTask):
                                 feats_np):
             # ``fetch-`` (not ``d2h-``) stage names: these waits run in
             # pool workers OVERLAPPED with the main thread's sync-execute
-            # waits — a device-prefixed name would double-count the link
-            # into device_busy_frac (the copies were started async at
-            # submit, so the device stream already accounts for them)
+            # waits, on copies that were started async at submit — they
+            # are not the main thread's transfers
             if rle_ok:
                 with stage("fetch-rle"):
                     packed = np.asarray(plo_d)
@@ -1046,7 +1057,7 @@ class FusedSegmentationBlocks(BlockTask):
         def drain(entry, retried: bool = False):
             # one block span per drained block (the cap-retry redo stays
             # inside the original block's span, under its cap-retry stage)
-            if retried or not telemetry.enabled():
+            if retried or not telemetry.tracing():
                 return _drain_body(entry, retried)
             with telemetry.span(f"block:{entry[0]}", cat="block",
                                 block=entry[0]) as sp:
@@ -1322,9 +1333,8 @@ class FusedSegmentationBlocks(BlockTask):
                         cfg["output_key"])
 
         def _write(bb, arr):
-            t0 = time.perf_counter()
-            ds_out[bb] = arr
-            stage_add("store-write", time.perf_counter() - t0)
+            with stage("store-write"):
+                ds_out[bb] = arr
             stage_bytes("store-write", arr.nbytes)
 
         def _drain_slab(sid, pool):

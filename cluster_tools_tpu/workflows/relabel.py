@@ -15,7 +15,7 @@ from typing import Any, Dict, Optional
 import numpy as np
 
 from ..core.blocking import Blocking
-from ..core.runtime import BlockTask
+from ..core.runtime import BlockTask, stage
 from ..core.storage import file_reader
 from ..core.workflow import Task
 from .write import WriteAssignments
@@ -52,10 +52,14 @@ class FindUniques(BlockTask):
         ds = f[cfg["input_key"]]
         uniques = []
         for block_id in job_config["block_list"]:
-            uniques.append(np.unique(ds[blocking.get_block(block_id).bb]))
+            with stage("store-read"):
+                block = ds[blocking.get_block(block_id).bb]
+            with stage("host-scan"):
+                uniques.append(np.unique(block))
             log_fn(f"processed block {block_id}")
-        out = (np.unique(np.concatenate(uniques)) if uniques
-               else np.zeros(0, dtype="uint64"))
+        with stage("host-scan"):
+            out = (np.unique(np.concatenate(uniques)) if uniques
+                   else np.zeros(0, dtype="uint64"))
         np.save(os.path.join(job_config["tmp_folder"],
                              f"{job_config['task_name']}_out_{job_id}.npy"),
                 out)
@@ -88,17 +92,20 @@ class FindLabeling(BlockTask):
         cfg = job_config["config"]
         uniques = []
         prefix = cfg["uniques_prefix"] + "_out_"
-        for name in os.listdir(cfg["tmp_root"]):
-            if name.startswith(prefix) and name.endswith(".npy"):
-                uniques.append(np.load(os.path.join(cfg["tmp_root"], name)))
-        ids = np.unique(np.concatenate(uniques)) if uniques else np.zeros(0, "uint64")
-        has_zero = ids.size and ids[0] == 0
-        nonzero = ids[1:] if has_zero else ids
-        new_ids = np.arange(1, nonzero.size + 1, dtype="uint64")
-        table = np.stack([nonzero, new_ids], axis=1)
-        if has_zero:
-            table = np.concatenate(
-                [np.zeros((1, 2), dtype="uint64"), table], axis=0)
+        with stage("host-scan"):
+            for name in os.listdir(cfg["tmp_root"]):
+                if name.startswith(prefix) and name.endswith(".npy"):
+                    uniques.append(
+                        np.load(os.path.join(cfg["tmp_root"], name)))
+            ids = (np.unique(np.concatenate(uniques)) if uniques
+                   else np.zeros(0, "uint64"))
+            has_zero = ids.size and ids[0] == 0
+            nonzero = ids[1:] if has_zero else ids
+            new_ids = np.arange(1, nonzero.size + 1, dtype="uint64")
+            table = np.stack([nonzero, new_ids], axis=1)
+            if has_zero:
+                table = np.concatenate(
+                    [np.zeros((1, 2), dtype="uint64"), table], axis=0)
         np.save(cfg["assignment_path"], table)
         log_fn(f"relabeling {nonzero.size} ids")
 

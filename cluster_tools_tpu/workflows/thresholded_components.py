@@ -21,7 +21,6 @@ from __future__ import annotations
 
 import json
 import os
-import time
 from typing import Any, Dict, List, Optional
 
 import numpy as np
@@ -208,8 +207,7 @@ class ResidentBlockComponents(BlockTask):
         import jax
         import jax.numpy as jnp
 
-        from ..core.runtime import (stage, stage_add, stage_bytes,
-                                    stream_window)
+        from ..core.runtime import stage, stage_bytes, stream_window
         from ..ops.sweep import rle_decode_packed
         from .fused_pipeline import _fragment_cache_put
 
@@ -281,9 +279,8 @@ class ResidentBlockComponents(BlockTask):
         write_futures = []
 
         def _write(bb, arr):
-            t0 = time.perf_counter()
-            ds_out[bb] = arr
-            stage_add("store-write", time.perf_counter() - t0)
+            with stage("store-write"):
+                ds_out[bb] = arr
             stage_bytes("store-write", arr.nbytes)
 
         cache_key = (os.path.abspath(cfg["output_path"]),

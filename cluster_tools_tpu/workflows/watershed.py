@@ -22,7 +22,7 @@ from typing import Any, Dict, Optional
 import numpy as np
 
 from ..core.blocking import Blocking
-from ..core.runtime import BlockTask
+from ..core.runtime import BlockTask, stage
 from ..core.storage import file_reader
 from ..core.workflow import Task
 from .relabel import RelabelWorkflow
@@ -388,14 +388,20 @@ def iter_ws_blocks_stream(blocks, cfg: Dict[str, Any]):
         b, handles = entry
         if fuse_filter or not min_size:
             ws, ok = handles
-            if not bool(ok):
+            with stage("sync-execute"):
+                ok = bool(ok)
+                ws = np.asarray(ws) if ok else None
+            if not ok:
                 return _fallback(b)
-            return np.asarray(ws).astype("uint64")
+            return ws.astype("uint64")
         ws, height, ok = handles
-        if not bool(ok):
+        with stage("sync-execute"):
+            ok = bool(ok)
+            if ok:
+                ws, height = np.asarray(ws), np.asarray(height)
+        if not ok:
             return _fallback(b)
-        return size_filter(np.asarray(ws), np.asarray(height),
-                           min_size).astype("uint64")
+        return size_filter(ws, height, min_size).astype("uint64")
 
     # bounded look-ahead: dispatch a few blocks ahead, drain as results are
     # consumed — unbounded queueing would hold every output buffer in HBM
@@ -436,48 +442,56 @@ def _ws_pipeline_3d(threshold: float, sigma_seeds: float,
             # device-side normalization of quantized boundary maps (the
             # host read path ships the raw bytes: 4x less link traffic)
             x = x.astype(jnp.float32) * (1.0 / 255.0)
-        fg = x < threshold
-        dt = distance_transform_edt(fg)
-        hmap = gaussian(x, sigma_weights) if sigma_weights else x
-        height = alpha * hmap + (1.0 - alpha) * (
-            1.0 - dt / jnp.maximum(dt.max(), 1e-6))
-        dt_smooth = gaussian(dt, sigma_seeds) if sigma_seeds else dt
-        maxima = local_maxima(dt_smooth, radius=2) & fg
-        seeds = connected_components(maxima, connectivity=3,
-                                     method="propagation")
-        if ws_method == "coarse":
-            # shared watershed core with the fused pipeline
-            # (workflows/fused_pipeline._resident_program) — identical
-            # composition, size filter integrated
-            from ..ops.watershed import _coarse_impl
+        # the fused resident program's stage scopes (op_name prefixes the
+        # profiler trace reports per device op)
+        with jax.named_scope("edt"):
+            fg = x < threshold
+            dt = distance_transform_edt(fg)
+        with jax.named_scope("smooth"):
+            hmap = gaussian(x, sigma_weights) if sigma_weights else x
+            height = alpha * hmap + (1.0 - alpha) * (
+                1.0 - dt / jnp.maximum(dt.max(), 1e-6))
+            dt_smooth = gaussian(dt, sigma_seeds) if sigma_seeds else dt
+        with jax.named_scope("seeds"):
+            maxima = local_maxima(dt_smooth, radius=2) & fg
+            seeds = connected_components(maxima, connectivity=3,
+                                         method="propagation")
+        with jax.named_scope("watershed"):
+            if ws_method == "coarse":
+                # shared watershed core with the fused pipeline
+                # (workflows/fused_pipeline._resident_program) — identical
+                # composition, size filter integrated
+                from ..ops.watershed import _coarse_impl
 
-            ws, ok = _coarse_impl(height, seeds, min_size, refine_rounds,
-                                  coarse_factor)
-        elif ws_method == "basins":
-            # the basin formulation fuses the size filter: small fragments
-            # are stripped and re-merged in ~2 extra cheap rounds instead
-            # of a full second watershed pass.  Tight capacities for speed;
-            # the ok flag is surfaced so the streaming drain can redo an
-            # overflowing block through the always-correct path
-            from ..ops.watershed import _basins_impl
+                ws, ok = _coarse_impl(height, seeds, min_size,
+                                      refine_rounds, coarse_factor)
+            elif ws_method == "basins":
+                # the basin formulation fuses the size filter: small
+                # fragments are stripped and re-merged in ~2 extra cheap
+                # rounds instead of a full second watershed pass.  Tight
+                # capacities for speed; the ok flag is surfaced so the
+                # streaming drain can redo an overflowing block through
+                # the always-correct path
+                from ..ops.watershed import _basins_impl
 
-            n = int(np.prod(fg.shape))
-            ws, ok = _basins_impl(height, seeds, None, 1, 64, min_size,
-                                  max(n // 64, 1024), max(n // 8, 4096))
-        else:
-            ok = jnp.bool_(True)
-            ws = seeded_watershed(height, seeds, None, connectivity=1,
-                                  method=ws_method)
-            if min_size:
-                # label ids are bounded by the voxel count (CC roots + 1),
-                # so a fixed-length bincount stays shape-static under jit
-                counts = jnp.bincount(ws.ravel().astype(jnp.int32),
-                                      length=int(np.prod(x.shape)) + 1)
-                small = counts < min_size
-                small = small.at[0].set(False)
-                kept = jnp.where(small[ws], 0, ws)
-                ws = seeded_watershed(height, kept, None, connectivity=1,
+                n = int(np.prod(fg.shape))
+                ws, ok = _basins_impl(height, seeds, None, 1, 64, min_size,
+                                      max(n // 64, 1024), max(n // 8, 4096))
+            else:
+                ok = jnp.bool_(True)
+                ws = seeded_watershed(height, seeds, None, connectivity=1,
                                       method=ws_method)
+                if min_size:
+                    # label ids are bounded by the voxel count (CC roots
+                    # + 1), so a fixed-length bincount stays shape-static
+                    # under jit
+                    counts = jnp.bincount(ws.ravel().astype(jnp.int32),
+                                          length=int(np.prod(x.shape)) + 1)
+                    small = counts < min_size
+                    small = small.at[0].set(False)
+                    kept = jnp.where(small[ws], 0, ws)
+                    ws = seeded_watershed(height, kept, None,
+                                          connectivity=1, method=ws_method)
         if return_height:  # for a host-side size filter downstream
             return ws, height, ok
         return ws, ok
@@ -676,14 +690,25 @@ class WatershedTask(BlockTask):
             # offset for global uniqueness (reference: watershed.py:307) —
             # uncompacted CC root indices range over the larger outer block
             # and would collide across blocks
-            nonzero = np.unique(inner[inner > 0])
-            compact = np.searchsorted(nonzero, inner).astype("uint64") + 1
-            compact[inner == 0] = 0
-            compact = np.where(
-                compact > 0,
-                compact + np.uint64(block_id) * label_offset_unit, 0)
-            ds_out[block.bb] = compact
+            with stage("host-map"):
+                nonzero = np.unique(inner[inner > 0])
+                compact = np.searchsorted(nonzero, inner).astype(
+                    "uint64") + 1
+                compact[inner == 0] = 0
+                compact = np.where(
+                    compact > 0,
+                    compact + np.uint64(block_id) * label_offset_unit, 0)
+            with stage("store-write"):
+                ds_out[block.bb] = compact
             log_fn(f"processed block {block_id}")
+
+        def _read_block(block_id: int) -> np.ndarray:
+            # runs on the prefetch thread; the consumer times its wait
+            # for the result as prefetch-wait, so no read second counts
+            # twice
+            with stage("store-read"):
+                return _read_padded_input(ds_in, blocking.get_block(block_id),
+                                          cfg, halo, raw=True)
 
         # plain 3d path: stream every block of the job through one fused
         # jitted pipeline with async dispatch — transfers and compute of
@@ -729,10 +754,7 @@ class WatershedTask(BlockTask):
             batched = jax.jit(jax.vmap(pipeline))
 
             block_ids = list(job_config["block_list"])
-            reads = prefetch_iter(
-                block_ids,
-                lambda bid: _read_padded_input(
-                    ds_in, blocking.get_block(bid), cfg, halo, raw=True))
+            reads = prefetch_iter(block_ids, _read_block)
             pending_ids: List[int] = []
             pending: List[np.ndarray] = []
 
@@ -785,10 +807,7 @@ class WatershedTask(BlockTask):
             block_ids = list(job_config["block_list"])
             # threaded read look-ahead: block i+2's store read overlaps
             # block i's device compute and block i-1's write
-            reads = prefetch_iter(
-                block_ids,
-                lambda bid: _read_padded_input(
-                    ds_in, blocking.get_block(bid), cfg, halo, raw=True))
+            reads = prefetch_iter(block_ids, _read_block)
             for bid, ws in zip(block_ids,
                                iter_ws_blocks_stream(reads, cfg)):
                 _write_result(bid, ws)

@@ -72,9 +72,7 @@ def rewrite_blocks(input_path: str, input_key: str, output_path: str,
     graph.  The edits/ assignment patcher uses this to refresh exactly
     the blocks an edit touched; every other output block stays as
     written by the bulk workflow."""
-    import time
-
-    from ..core.runtime import stage, stage_add, stage_bytes
+    from ..core.runtime import stage, stage_bytes
     from .fused_pipeline import fragment_cache_get
 
     in_place = (input_path == output_path and input_key == output_key)
@@ -96,9 +94,8 @@ def rewrite_blocks(input_path: str, input_key: str, output_path: str,
             stage_bytes("store-read", seg.nbytes)
         with stage("host-map"):
             out = apply_assignment_table(seg, table)
-        t0 = time.perf_counter()
-        ds_out[bb] = out
-        stage_add("store-write", time.perf_counter() - t0)
+        with stage("store-write"):
+            ds_out[bb] = out
         stage_bytes("store-write", out.nbytes)
         if log_fn:
             log_fn(f"rewrote block {block_id}")
@@ -173,9 +170,7 @@ class WriteAssignments(BlockTask):
 
     @classmethod
     def process_job(cls, job_id: int, job_config: Dict[str, Any], log_fn):
-        import time
-
-        from ..core.runtime import stage, stage_add, stage_bytes, writer_pool
+        from ..core.runtime import stage, stage_bytes, writer_pool
 
         cfg = job_config["config"]
         blocking = Blocking(cfg["shape"], cfg["block_shape"])
@@ -193,9 +188,8 @@ class WriteAssignments(BlockTask):
         from .fused_pipeline import fragment_cache_get
 
         def _write(bb, out):
-            t0 = time.perf_counter()
-            ds_out[bb] = out
-            stage_add("store-write", time.perf_counter() - t0)
+            with stage("store-write"):
+                ds_out[bb] = out
             stage_bytes("store-write", out.nbytes)
 
         def _map_cached(block_id, bb, local, f_off):

@@ -162,7 +162,6 @@ def test_trace_artifact_schema(path):
     roll = doc.get("rollups")
     assert isinstance(roll, dict), path
     assert isinstance(roll.get("stage_seconds"), dict), path
-    float(roll["device_busy_s"])
     # the gate itself must accept the artifact (self-diff, in-library)
     diff = telemetry.diff_rollups(roll, roll)
     assert diff["regressed"] is False

@@ -166,3 +166,55 @@ def test_fused_hybrid_ws_method(tmp_path, tmp_workdir):
     _, counts = np.unique(ws, return_counts=True)
     assert counts.min() >= 5  # local refill keeps fragments reasonable
     assert len(np.unique(seg)) >= 2
+
+
+#: the resident program's stage scopes, as the profiler trace reads them
+RESIDENT_SCOPES = ("edt", "smooth", "seeds", "watershed", "relabel", "pairs",
+                   "edge_stats", "rle")
+
+
+def _op_name_scopes(compiled, program):
+    """The second component of every ``op_name`` under ``jit(program)``
+    in a compiled program's HLO metadata."""
+    import re
+
+    names = re.findall(r'op_name="([^"]*)"', compiled.as_text())
+    return {n.split("/")[1] for n in names
+            if n.startswith(f"jit({program})/") and n.count("/") >= 2}
+
+
+def _resident(in_dtype):
+    import jax
+    import jax.numpy as jnp
+
+    from cluster_tools_tpu.workflows.fused_pipeline import _resident_program
+
+    prog = _resident_program((12, 20, 20), (2, 2, 2), in_dtype, 0.4, 2.0,
+                             2.0, 0.8, 25, 4096, 1 << 12, 3, 1 << 14, 2)
+    return prog.lower(jax.ShapeDtypeStruct((20, 36, 36), in_dtype),
+                      jax.ShapeDtypeStruct((6,), jnp.int32)).compile(), \
+        "run", RESIDENT_SCOPES
+
+
+def _watershed_pipeline(in_dtype):
+    import jax
+
+    from cluster_tools_tpu.workflows.watershed import _ws_pipeline_3d
+
+    prog = _ws_pipeline_3d(0.4, 2.0, 2.0, 0.8, 25, ws_method="coarse")
+    return prog.lower(jax.ShapeDtypeStruct((12, 20, 20), in_dtype)
+                      ).compile(), "pipeline", RESIDENT_SCOPES[:4]
+
+
+@pytest.mark.parametrize("build_program, in_dtype", [
+    (_resident, "uint8"), (_resident, "float32"),
+    (_watershed_pipeline, "uint8")],
+    ids=["resident-uint8", "resident-float32", "watershed-uint8"])
+def test_device_programs_carry_stage_scopes(build_program, in_dtype):
+    """Every stage scope of the resident program (both input paths), and
+    the watershed stages of the classic pipeline it shares its core
+    with, is an ``op_name`` prefix of the compiled program: the scope the
+    profiler trace reports for each device op."""
+    compiled, program, scopes = build_program(in_dtype)
+    found = _op_name_scopes(compiled, program)
+    assert set(scopes) <= found, sorted(found)

@@ -1,10 +1,10 @@
 """Structured span tracing (core/telemetry.py) — ISSUE 15.
 
 Tier-1 coverage for the span recorder (thread safety, ring bound,
-parent/child nesting, off-by-default zero-recording), the Chrome
-trace-event exporter (schema, nesting, fixed-clock determinism), the
-span-derived rollups (device-busy, bubble fraction, queue-wait
-histograms), the Prometheus writer, the stage-name registry lint, the
+parent/child nesting, off-by-default zero-recording), the profiler
+sink (program spans in a JAX profiler trace), the Chrome trace-event
+exporter (schema, nesting, fixed-clock determinism), the span-derived
+rollups (stage seconds, queue-wait histograms), the Prometheus writer, the stage-name registry lint, the
 runtime instrumentation (stage_add span emission with bit-identical
 accumulators, BoundedPool queue-wait spans, attempt spans + correlation
 ids across retries), and the telemetry-off overhead gate.  No XLA
@@ -189,23 +189,16 @@ def test_chrome_trace_deterministic_under_fixed_clock(tmp_path):
 # ---------------------------------------------------------------------------
 
 def test_rollups_exact_on_known_intervals(fake_clock):
-    """Device-busy (sum AND merged-timeline), bubble fraction and the
-    queue-wait histogram against hand-checkable interval arithmetic."""
+    """Stage seconds and entries, the trace window and the queue-wait
+    histogram against hand-checkable interval arithmetic."""
     telemetry.record("sync-execute", 0.0, 1.0)
     telemetry.record("d2h-dense", 0.5, 1.5)       # overlaps the first
-    telemetry.record("host-map", 0.0, 3.0)        # host: never busy time
+    telemetry.record("host-map", 0.0, 3.0)
+    telemetry.record("sync-execute", 2.0, 2.5, count=2)
     telemetry.record("wait-a", 0.0, 0.005, cat="queue-wait")
     telemetry.record("wait-b", 0.0, 0.05, cat="queue-wait")
     spans = telemetry.spans_snapshot()
-    assert telemetry.device_busy_seconds(spans) == pytest.approx(2.0)
-    assert telemetry.busy_timeline(spans) == [(0.0, 1.5)]
-    # SUM semantics (matches the device_busy_frac accumulator)
-    assert telemetry.device_busy_fraction(4.0, spans) == \
-        pytest.approx(0.5)
-    # merged-timeline semantics: 1 - 1.5/3 of the window has no device
-    # stage active
-    assert telemetry.pipeline_bubble_fraction(spans, wall=3.0) == \
-        pytest.approx(0.5)
+    assert telemetry.trace_window(spans) == pytest.approx(3.0)
     hist = telemetry.queue_wait_histogram(
         bins=(0.01, 0.1), spans=spans)
     assert hist["count"] == 2
@@ -214,24 +207,13 @@ def test_rollups_exact_on_known_intervals(fake_clock):
     assert hist["buckets"]["0.1"] == 2
     assert hist["buckets"]["+Inf"] == 2
     summ = telemetry.summary(wall=4.0)
-    assert summ["device_busy_s"] == pytest.approx(2.0)
-    assert summ["device_busy_frac"] == pytest.approx(0.5)
+    assert summ["stage_seconds"] == {"host-map": 3.0, "sync-execute": 1.5,
+                                     "d2h-dense": 1.0}
+    assert summ["stage_entries"] == {"sync-execute": 3, "host-map": 1,
+                                     "d2h-dense": 1}
+    assert summ["window_s"] == pytest.approx(3.0)
+    assert summ["wall_s"] == pytest.approx(4.0)
     assert summ["by_cat"]["queue-wait"] == 2
-
-
-def test_device_busy_crosschecks_accumulator(fake_clock):
-    """The span view and the flat accumulator are fed by the SAME
-    stage_add calls — their device-busy sums must agree (the acceptance
-    bound is 5%; in-process they agree to float precision)."""
-    st0 = runtime.stages_snapshot()
-    for sec in (0.25, 0.5, 0.125):
-        runtime.stage_add("sync-execute", sec)
-    runtime.stage_add("h2d-upload", 0.1)
-    runtime.stage_add("host-map", 9.0)            # must NOT count
-    acc_busy = sum(v for k, v in runtime.stages_delta(st0).items()
-                   if k.startswith(telemetry.DEVICE_STAGE_PREFIXES))
-    span_busy = telemetry.device_busy_seconds()
-    assert span_busy == pytest.approx(acc_busy, rel=1e-6)
 
 
 # ---------------------------------------------------------------------------
@@ -601,8 +583,6 @@ def test_dropped_span_counter_exported(fake_clock, tmp_path):
 _BASE_ROLLUPS = {
     "stage_seconds": {"sync-execute": 8.0, "h2d-upload": 0.6,
                       "host-solve": 2.0},
-    "device_busy_s": 8.6,
-    "pipeline_bubble_frac": 0.02,
 }
 
 
@@ -612,29 +592,11 @@ def test_diff_rollups_pass_path():
     cand = {
         "stage_seconds": {"sync-execute": 8.2, "h2d-upload": 0.5,
                           "host-solve": 2.2},   # host +10%: warning only
-        "device_busy_s": 8.7,
-        "pipeline_bubble_frac": 0.03,
     }
     diff = telemetry.diff_rollups(_BASE_ROLLUPS, cand)
     assert diff["regressed"] is False
     assert diff["regressions"] == []
     assert diff["stages"]["sync-execute"]["regressed"] is False
-
-
-def test_diff_rollups_fail_path_device_busy():
-    """A device stage past threshold regresses AND the device-busy total
-    regresses — the acceptance criterion's nonzero-exit condition."""
-    cand = {
-        "stage_seconds": {"sync-execute": 12.0, "h2d-upload": 0.6,
-                          "host-solve": 2.0},
-        "device_busy_s": 12.6,
-        "pipeline_bubble_frac": 0.02,
-    }
-    diff = telemetry.diff_rollups(_BASE_ROLLUPS, cand)
-    assert diff["regressed"] is True
-    assert "stage:sync-execute" in diff["regressions"]
-    assert "device_busy_s" in diff["regressions"]
-    assert diff["device_busy"]["delta_s"] == pytest.approx(4.0)
 
 
 def test_diff_rollups_host_regression_warns_not_gates():
@@ -647,22 +609,10 @@ def test_diff_rollups_host_regression_warns_not_gates():
 
 
 def test_diff_rollups_abs_floor_ignores_micro_stages():
-    base = {"stage_seconds": {"sync-execute": 0.001},
-            "device_busy_s": 0.001}
-    cand = {"stage_seconds": {"sync-execute": 0.01},
-            "device_busy_s": 0.01}   # 10x relative but under the floor
+    base = {"stage_seconds": {"sync-execute": 0.001}}
+    cand = {"stage_seconds": {"sync-execute": 0.01}}   # 10x, under the floor
     diff = telemetry.diff_rollups(base, cand)
     assert diff["regressed"] is False
-
-
-def test_diff_rollups_bubble_gate():
-    cand = dict(_BASE_ROLLUPS, pipeline_bubble_frac=0.2)
-    diff = telemetry.diff_rollups(_BASE_ROLLUPS, cand)
-    assert diff["regressed"] is True
-    assert "pipeline_bubble_frac" in diff["regressions"]
-    # configurable threshold: widen it and the gate opens
-    ok = telemetry.diff_rollups(_BASE_ROLLUPS, cand, bubble_abs=0.5)
-    assert ok["regressed"] is False
 
 
 def test_diff_rollups_new_stage_in_candidate_gates():
@@ -677,7 +627,7 @@ def test_diff_rollups_new_stage_in_candidate_gates():
 
 def test_bench_trace_diff_cli_pass_and_fail(tmp_path):
     """End-to-end CLI: exit 0 on self-compare, nonzero on a synthetic
-    device-busy regression (both paths of the acceptance criterion)."""
+    device-stage regression (both paths of the acceptance criterion)."""
     import subprocess
     import sys as _sys
 
@@ -686,7 +636,7 @@ def test_bench_trace_diff_cli_pass_and_fail(tmp_path):
     regr = str(tmp_path / "regr.json")
     with open(base, "w") as f:
         json.dump({"rollups": _BASE_ROLLUPS}, f)
-    cand = {**_BASE_ROLLUPS, "device_busy_s": 12.6,
+    cand = {**_BASE_ROLLUPS,
             "stage_seconds": {**_BASE_ROLLUPS["stage_seconds"],
                               "sync-execute": 12.0}}
     with open(regr, "w") as f:
@@ -707,7 +657,8 @@ def test_bench_trace_diff_cli_pass_and_fail(tmp_path):
     assert bad.returncode == 1, bad.stdout + bad.stderr
     out = json.loads(bad.stdout)
     assert out["regressed"] is True
-    assert "device_busy_s" in out["regressions"]
+    assert "stage:sync-execute" in out["regressions"]
+    assert out["stages"]["sync-execute"]["delta_s"] == pytest.approx(4.0)
 
 
 # ---------------------------------------------------------------------------
@@ -1011,8 +962,8 @@ def _synthetic_shard(path, pidx, wall_anchor, perf_anchor, spans):
 def test_merge_chrome_traces_rebases_and_remaps(tmp_path):
     """Two shards with different clock origins merge into ONE trace:
     pids remapped per process, timestamps rebased through the
-    barrier-aligned anchors, and the merged rollups aggregate
-    device_busy_s across the mesh (cross-checked per process)."""
+    barrier-aligned anchors, and the merged rollups aggregate stage
+    seconds across the mesh (span counts cross-checked per process)."""
     p0 = str(tmp_path / "trace_shard_p0.json")
     p1 = str(tmp_path / "trace_shard_p1.json")
     # process 0: perf clock starts at 1000; process 1: at 5; their wall
@@ -1028,10 +979,11 @@ def test_merge_chrome_traces_rebases_and_remaps(tmp_path):
     assert m["n_processes"] == 2
     assert [p["pid"] for p in m["processes"]] == [1, 2]
     assert [p["clock_offset_s"] for p in m["processes"]] == [0.0, 0.5]
-    busy = {p["process_index"]: p["device_busy_s"]
-            for p in m["processes"]}
-    assert busy == {0: 0.5, 1: 0.25}
-    assert m["rollups"]["device_busy_s"] == pytest.approx(0.75)
+    assert {p["process_index"]: p["n_spans"]
+            for p in m["processes"]} == {0: 2, 1: 1}
+    assert m["rollups"]["n_spans"] == 3
+    assert m["rollups"]["stage_seconds"]["sync-execute"] == \
+        pytest.approx(0.75)
     assert m["rollups"]["memory"]["peak_device_gb"] == pytest.approx(2.0)
     with open(out) as f:
         events = json.load(f)["traceEvents"]
@@ -1118,3 +1070,131 @@ def test_install_flight_recorder_chains_and_uninstalls(tmp_path):
         assert doc["extra"]["stage"] == "serve"
     finally:
         _sys.excepthook = prev
+
+
+# ---------------------------------------------------------------------------
+# profiler sink: program spans in a JAX profiler trace, on the thread that
+# does the work
+# ---------------------------------------------------------------------------
+
+class _StepTime:
+    """Stand-in for ``runtime.time``: ``perf_counter`` advances a fixed
+    step per call, everything else is the real module."""
+
+    def __init__(self, step):
+        self.perf_counter = FakeClock(step)
+
+    def __getattr__(self, name):
+        return getattr(time, name)
+
+
+def _host_lines(trace_dir):
+    """{line index: [event names]} of the host plane of the trace the
+    profiler wrote under ``trace_dir`` (one line per OS thread)."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    pd = ProfileData.from_file(path)
+    (plane,) = [p for p in pd.planes if p.name == "/host:CPU"]
+    return {i: [ev.name for ev in line.events]
+            for i, line in enumerate(plane.lines)}
+
+
+@pytest.fixture(scope="module")
+def profiled_lines(tmp_path_factory):
+    """One CPU profiler trace holding a ``runtime.stage`` block and a
+    ``telemetry.span`` on the main thread and a ``BoundedPool`` task on
+    its worker thread; each site also opens a plain marker annotation on
+    its own thread."""
+    import jax
+    from jax.profiler import TraceAnnotation
+
+    trace_dir = str(tmp_path_factory.mktemp("profile"))
+
+    def pooled():
+        with TraceAnnotation("marker.pool"):
+            pass
+
+    jax.profiler.start_trace(trace_dir)
+    try:
+        with runtime.stage("host-map"):
+            with TraceAnnotation("marker.stage"):
+                pass
+        with telemetry.span("fill", cat="attempt", n_jobs=1):
+            with TraceAnnotation("marker.span"):
+                pass
+        with runtime.BoundedPool(1) as pool:
+            pool.submit(pooled)
+    finally:
+        jax.profiler.stop_trace()
+    return _host_lines(trace_dir)
+
+
+@pytest.mark.parametrize("site, event", [
+    ("stage", "ctt.stage.host-map"),
+    ("span", "ctt.attempt.fill"),
+    ("pool", "ctt.pool.pool:pooled"),
+])
+def test_profiler_sink_spans_on_the_working_thread(profiled_lines, site,
+                                                   event):
+    """Whenever the profiler records, stages, spans and pool tasks are
+    ``ctt.*`` events of the trace, on the line of the thread that ran
+    them (the pool task on its worker, not on the submitting thread);
+    the ring stays off."""
+    line = {name: i for i, names in profiled_lines.items()
+            for name in names}
+    assert event in line, profiled_lines
+    assert line[event] == line[f"marker.{site}"]
+    if site == "pool":
+        assert line[event] != line["marker.stage"]
+    assert telemetry.spans_snapshot() == []
+
+
+def test_profiler_off_records_nothing_and_keeps_accumulators(tmp_path,
+                                                             monkeypatch):
+    """With no profiler trace recording, a stage or span opens no
+    annotation (the span is the shared no-op), and the accumulators read
+    what a traced run of the same calls reads; the traced run's trace
+    holds exactly its program spans."""
+    import jax
+
+    monkeypatch.setattr(runtime, "time", _StepTime(0.25))
+    assert not telemetry.profiling() and not telemetry.tracing()
+    assert telemetry.open_annotation("stage.host-map") is None
+    assert telemetry.span("w", cat="job") is telemetry.span("x")
+
+    def work():
+        with runtime.stage("host-map"):
+            pass
+        with telemetry.span("w", cat="job"):
+            with runtime.stage("store-write"):
+                runtime.stage_bytes("store-write", 64)
+
+    trace_dir = str(tmp_path / "on")
+    deltas = []
+    for traced in (True, False):
+        st0 = runtime.stages_snapshot()
+        cn0 = runtime.counts_snapshot()
+        by0 = runtime.bytes_snapshot()
+        if traced:
+            jax.profiler.start_trace(trace_dir)
+        try:
+            work()
+        finally:
+            if traced:
+                jax.profiler.stop_trace()
+        deltas.append((runtime.stages_delta(st0), runtime.counts_delta(cn0),
+                       runtime.bytes_delta(by0)))
+    (on_s, on_c, on_b), (off_s, off_c, off_b) = deltas
+    assert on_c == off_c == {"host-map": 1, "store-write": 1}
+    assert on_b == off_b == {"store-write": 64.0}
+    assert on_s == pytest.approx(off_s)
+    assert off_s == pytest.approx({"host-map": 0.25, "store-write": 0.25})
+    events = sorted(n for names in _host_lines(trace_dir).values()
+                    for n in names if n.startswith(telemetry.PROFILER_PREFIX))
+    assert events == ["ctt.job.w", "ctt.stage.host-map",
+                      "ctt.stage.store-write"]
+    assert telemetry.spans_snapshot() == []

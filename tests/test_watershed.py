@@ -419,3 +419,36 @@ def test_host_watershed_block_quality():
     mask[:, :, 12:] = False
     host_m = run_ws_block_host(vol, cfg, mask=mask)
     assert (host_m[:, :, 12:] == 0).all()
+
+
+def test_watershed_workflow_records_host_stages(tmp_workdir, tmp_path):
+    """The streamed watershed task and the relabel passes time their host
+    work as stages (and so as ``ctt.stage.*`` spans in a profiler trace):
+    block reads on the prefetch thread, the wait for them, the device
+    waits, the relabel map and the fragment writes; FindUniques its reads
+    and scans, FindLabeling its scan."""
+    import glob
+    import json
+    import os
+
+    tmp_folder, config_dir = tmp_workdir
+    shape = (24, 24, 24)
+    path = str(tmp_path / "data.n5")
+    with file_reader(path) as f:
+        f.require_dataset("boundaries", shape=shape, chunks=(12, 12, 12),
+                          dtype="float32")[...] = _boundary_volume(shape)
+    assert build([WatershedWorkflow(
+        input_path=path, input_key="boundaries", output_path=path,
+        output_key="ws", tmp_folder=tmp_folder, config_dir=config_dir,
+        max_jobs=1, target="inline")], raise_on_failure=True)
+    stages = {}
+    for sf in glob.glob(os.path.join(tmp_folder, "*.status")):
+        with open(sf) as f:
+            st = json.load(f)
+        stages[st["task"].split("_relabel")[0]] = st["stage_counts"]
+    assert {"store-read", "prefetch-wait", "sync-execute", "host-map",
+            "store-write"} <= set(stages["watershed"]), stages
+    # one fragment write per block of the conftest's [10, 10, 10] grid
+    assert stages["watershed"]["store-write"] == 27
+    assert {"store-read", "host-scan"} <= set(stages["find_uniques"])
+    assert "host-scan" in stages["find_labeling"]
