@@ -399,9 +399,9 @@ def _resident_program(outer_shape, halo, in_dtype, threshold: float,
             # (the histogram formulation is exact); each pair compacts
             # ONCE carrying both side samples, PACKED into two int32
             # channels — (u,v) as u*2^15+v and the two side bytes as
-            # a*256+b — so the compaction pays two scatter passes instead
-            # of four (each O(n) scatter over the ~40M pair elements is
-            # ~0.3 s; this stage was 55% of the whole block program).
+            # a*256+b — so the compaction sorts and scatters two channels
+            # instead of four (56 ms for the two over the ~39M pair slots
+            # on a v5e chip, see ``compact_valid``).
             # Packing needs every dense label < 2^15: any block that
             # dense would overflow e_max anyway, and the guard below
             # routes it to the host fallback via the ok flag
