@@ -25,8 +25,12 @@ def read(path: str, key: str, begin=None, end=None) -> np.ndarray:
 
 
 def write(path: str, key: str, data: np.ndarray, chunks) -> None:
+    """Create (or replace) the dataset ``key`` holding ``data``, of any
+    rank, in chunks of ``chunks`` (one entry per axis, C order)."""
     import tensorstore as ts
 
+    if len(chunks) != data.ndim:
+        raise ValueError(f"chunks {tuple(chunks)} for a {data.ndim}-d array")
     arr = ts.open({
         "driver": "n5", "kvstore": {"driver": "file", "path": path},
         "path": key,

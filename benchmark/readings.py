@@ -27,19 +27,15 @@ import run
 
 def read(cfg, mix, seeds, workers=None):
     """Yield one dict of readings per seed."""
-    import n5
-    import worley
     from cluster_tools_tpu.workflows import fused_pipeline
 
     ref = importlib.import_module("refs." + cfg["reference"]["name"])
     work = os.path.join(run.WORK, "readings")
-    block = tuple(cfg["global_config"]["block_shape"])
     for seed in seeds:
         shutil.rmtree(work, ignore_errors=True)
         os.makedirs(work)
-        vol = worley.generate(tuple(cfg["shape"]), seed, mix)
         input_path = os.path.join(work, "input.n5")
-        n5.write(input_path, cfg["input_key"], vol, block)
+        vol = run.make_input(cfg, mix, seed, input_path)
         chain = os.path.join(work, "chain")
         wall, _ = run.run_chain(cfg, input_path, chain)
         fused_pipeline.clear_caches()

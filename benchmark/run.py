@@ -6,11 +6,19 @@
 
 Everything a cell needs is found by name from ``BENCHMARK.json``: the
 configuration's file (``benchmark/configs/<config>.json``: the deployment,
-its workflow and task configs, the reference and the limits of what
-``correct`` compares), the traffic mix (``benchmark/traffic/<mix>.json``,
-read by the one generator ``benchmark/worley.py``), the reference
+its input, its workflow and task configs, the reference and the limits of
+what ``correct`` compares), the traffic mix
+(``benchmark/traffic/<mix>.json``), the reference
 (``benchmark/refs/<name>.py``) and one reader per per-layer metric
 (``benchmark/metrics/<metric>.py``).
+
+The configuration names its input under an optional ``"input"`` object:
+``"generator"``, a module ``benchmark/<generator>.py`` (default
+``worley``, the boundary map), whose ``generate(shape, seed, mix,
+**args)`` makes the array from the seed and the mix; and ``"args"``,
+those keyword arguments (default none).  The array is stored in chunks of
+the block, one chunk per channel along any leading axis it has beyond the
+configuration's spatial ``shape``.
 
 Set-up (``setup_s``, from process start to the window's start): device
 generation of the input from ``--seed``, its N5 write, and one warm-up chain
@@ -90,6 +98,39 @@ def resolve(bench, cell):
     wl = dict(wl, e2e=[m for m in bench["end_to_end"]
                        if cell in m.get("workloads", [cell])])
     return wl, cfg, mix, per_layer
+
+
+def input_spec(cfg):
+    """(generator module, its keyword arguments) that the configuration's
+    ``"input"`` names; with no ``"input"``, the boundary map of ``worley``.
+    The module is imported by name from ``benchmark/``, so that worker
+    processes can unpickle from it."""
+    spec = cfg.get("input", {})
+    name = spec.get("generator", "worley")
+    if not (name.isidentifier()
+            and os.path.isfile(os.path.join(HERE, name + ".py"))):
+        raise SystemExit(f"configuration {cfg['name']!r}: no generator "
+                         f"benchmark/{name}.py")
+    return importlib.import_module(name), dict(spec.get("args", {}))
+
+
+def make_input(cfg, mix, seed, path, setup=None):
+    """Generate the configuration's input from ``seed`` and write it as the
+    N5 dataset ``cfg["input_key"]`` under ``path``; returns the array.  The
+    seconds of each part go into ``setup`` (``generate_s``, ``store_s``)."""
+    import n5
+
+    setup = {} if setup is None else setup
+    gen, args = input_spec(cfg)
+    block = tuple(cfg["global_config"]["block_shape"])
+    t = time.perf_counter()
+    vol = gen.generate(tuple(cfg["shape"]), seed, mix, **args)
+    setup["generate_s"] = time.perf_counter() - t
+    chunks = (1,) * (vol.ndim - len(block)) + block  # one per channel
+    t = time.perf_counter()
+    n5.write(path, cfg["input_key"], vol, chunks)
+    setup["store_s"] = time.perf_counter() - t
+    return vol
 
 
 class TaskSpans(logging.Handler):
@@ -237,9 +278,6 @@ def execute(cell, wl, cfg, mix, per_layer, seed, seconds, trace,
         if devs[0].device_kind not in peaks:
             raise NoChip(f"device kind {devs[0].device_kind!r} is not in "
                          "benchmark/peaks.json")
-    import n5
-    import worley
-
     say(f"device {devs[0].device_kind} x{len(devs)}; compile cache "
         f"{use_cache()}")
     counter = CompileCounter()
@@ -255,13 +293,8 @@ def execute(cell, wl, cfg, mix, per_layer, seed, seconds, trace,
         n_blocks *= -(-s // b)
     setup = {}
 
-    t = time.perf_counter()
-    vol = worley.generate(shape, seed, mix)
-    setup["generate_s"] = time.perf_counter() - t
-    t = time.perf_counter()
     input_path = os.path.join(work, "input.n5")
-    n5.write(input_path, cfg["input_key"], vol, block)
-    setup["store_s"] = time.perf_counter() - t
+    vol = make_input(cfg, mix, seed, input_path, setup)
     t = time.perf_counter()
     roi = None
     if cfg["warmup"] == "roi":

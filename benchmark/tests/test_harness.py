@@ -45,6 +45,14 @@ def test_every_workload_resolves(bench):
             assert hasattr(run.load_module(path, m["name"]), "read")
 
 
+def test_every_configuration_names_a_generator(bench):
+    for c in bench["configs"]:
+        with open(os.path.join(ROOT, c["file"])) as f:
+            gen, args = run.input_spec(json.load(f))
+        assert callable(getattr(gen, "generate", None)), gen.__name__
+        assert isinstance(args, dict)
+
+
 def test_config_files_are_unique_and_under_paths(bench):
     files = [c["file"] for c in bench["configs"]]
     assert len(set(files)) == len(files)

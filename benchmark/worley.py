@@ -1,7 +1,10 @@
-"""The benchmark's one traffic generator: a CREMI-like boundary map.
+"""The default input generator: a CREMI-like boundary map.
 
 A traffic mix is a JSON file of parameters under ``benchmark/traffic``;
-this module reads it.  The statistics are those of ``bench.synthetic_instance``
+this module reads it (``load_mix``).  A configuration that names no other
+generator under ``"input"`` takes this one's ``generate(shape, seed,
+mix)``; another generator, such as ``affinities``, builds on its cells
+and map (``with_labels``).  The statistics are those of ``bench.synthetic_instance``
 (Voronoi cells of ``cell_voxels`` voxels on average, ridges
 ``exp(-0.5 ((d2 - d1) / ridge_sigma)^2)`` from the distances to the nearest
 and second-nearest cell centre, requantized to uint8), but the centres lie on
