@@ -84,6 +84,8 @@ STAGE_REGISTRY = {
     "host-scan", "host-solve",
     # count only: mesh_resident requested, streamed per-block path taken
     "mesh-fallback",
+    # count only: edges a mutex-watershed host scan consumed
+    "scan-edges",
     # pool-worker fetches, overlapped with the main thread's sync-execute
     # waits (fetch- rather than d2h-: the copies were started at submit)
     "fetch-dense", "fetch-rle",

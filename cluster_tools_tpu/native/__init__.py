@@ -88,10 +88,8 @@ def _load() -> Optional[ctypes.CDLL]:
                                        p_f64, p_u64]
         lib.mws_clustering.restype = i64
         p_i32 = np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS")
-        p_u8 = np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS")
-        lib.mws_clustering_sorted.argtypes = [i64, i64, p_i32, p_i32, p_u8,
-                                              p_u64]
-        lib.mws_clustering_sorted.restype = i64
+        lib.mws_clustering_packed.argtypes = [i64, i64, p_i32, p_i32, p_u64]
+        lib.mws_clustering_packed.restype = i64
         lib.graph_watershed.argtypes = [i64, i64, p_i64, p_f64, p_u64]
         lib.lmc_gaec.argtypes = [i64, i64, p_i64, p_f64, i64, p_i64, p_f64,
                                  p_u64]
@@ -463,29 +461,30 @@ def mutex_clustering(n_nodes: int, uv_attractive: np.ndarray,
     return _py_mws(n_nodes, uva, wa, uvm, wm)
 
 
-def mutex_clustering_sorted(n_nodes: int, u: np.ndarray, v: np.ndarray,
-                            mutex_flag: np.ndarray) -> np.ndarray:
+def mutex_clustering_packed(n_nodes: int, u: np.ndarray,
+                            v_packed: np.ndarray) -> np.ndarray:
     """Mutex-watershed union-find scan over a PRE-SORTED edge stream
-    (descending priority; the device extracted and sorted the edges).
-    ``u[i] < 0`` marks dropped edges; ``mutex_flag[i] != 0`` marks mutex
-    edges.  Only the inherently sequential scan stays on the host —
-    the std::stable_sort of tens of millions of 24-byte edge structs
-    was the dominant cost of :func:`mutex_clustering`."""
+    (descending priority; the device extracted and sorted the edges), read
+    as it comes down (``ops/mws._sorted_edges_device``): ``u[i] < 0`` or
+    bit 29 of ``v_packed[i]`` marks a dropped edge, bit 30 a mutex edge,
+    and bits 0-28 hold the partner.  Only the inherently sequential scan
+    stays on the host — the std::stable_sort of tens of millions of
+    24-byte edge structs was the dominant cost of
+    :func:`mutex_clustering`."""
     u = np.ascontiguousarray(u, dtype=np.int32)
-    v = np.ascontiguousarray(v, dtype=np.int32)
-    mutex_flag = np.ascontiguousarray(mutex_flag, dtype=np.uint8)
+    v_packed = np.ascontiguousarray(v_packed, dtype=np.int32)
     lib = _load()
-    out = np.empty(n_nodes, dtype=np.uint64)
     if lib is not None:
-        lib.mws_clustering_sorted(n_nodes, len(u), u, v, mutex_flag, out)
+        out = np.empty(n_nodes, dtype=np.uint64)
+        lib.mws_clustering_packed(n_nodes, len(u), u, v_packed, out)
         return out
     # pure-python fallback: rebuild (uv, w) lists in stream order with a
     # descending fake priority so _py_mws's sort is a stable no-op
-    keep = u >= 0
-    n = int(keep.sum())
-    pri = np.arange(n, 0, -1, dtype="float64")
-    am = mutex_flag[keep] != 0
-    uv = np.stack([u[keep], v[keep]], axis=1).astype("int64")
+    keep = (u >= 0) & ((v_packed >> 29) & 1 == 0)
+    pri = np.arange(int(keep.sum()), 0, -1, dtype="float64")
+    am = (v_packed[keep] >> 30) & 1 != 0
+    uv = np.stack([u[keep], v_packed[keep] & ((1 << 29) - 1)],
+                  axis=1).astype("int64")
     return _py_mws(n_nodes, uv[~am], pri[~am], uv[am], pri[am])
 
 

@@ -304,52 +304,134 @@ int64_t mws_clustering(int64_t n_nodes, int64_t n_attr, const int64_t* uv_attr,
 // priority on the accelerator, so this is only the inherently sequential
 // constrained union-find — no 24-byte edge structs, no host sort (the
 // std::stable_sort above is the dominant cost of mws_clustering at
-// tens of millions of edges).  u[i] < 0 marks a dropped edge (the
-// zero-affinity filter applied on device).  mutex_flag[i] != 0 marks a
-// mutex (repulsive) edge.
-int64_t mws_clustering_sorted(int64_t n_nodes, int64_t n_edges,
-                              const int32_t* u, const int32_t* v,
-                              const uint8_t* mutex_flag,
+// tens of millions of edges).  The stream is read as it comes down
+// (ops/mws._sorted_edges_device): u[i] < 0 or bit 29 of v_packed[i] marks
+// a dropped edge, bit 30 a mutex (repulsive) edge, and bits 0-28 hold the
+// partner.
+//
+// Mutex bookkeeping: a root that holds mutexes owns one list of partner
+// ids (each the partner's root when the entry was made) in a shared pool.
+// An entry goes stale when its partner merges away and is resolved through
+// find() when read, so a merge rewires nothing.  Two clusters are
+// separated iff an entry of one resolves to the other's root; each mutex
+// edge is entered in both lists, so scanning the shorter list decides.  A
+// merge appends the shorter list to the longer.  A full list is first
+// resolved, sorted and deduplicated, and only grows (to a range of twice
+// the capacity) if that leaves it more than half full.  Ranges are powers
+// of two and a released range is reused for the next list of its size.
+// The merge decisions, and so the labels, are those of mws_clustering's
+// bookkeeping (a hash set per voxel, every partner rewired on each
+// merge), without its per-entry allocations.
+int64_t mws_clustering_packed(int64_t n_nodes, int64_t n_edges,
+                              const int32_t* u, const int32_t* v_packed,
                               uint64_t* labels_out) {
-    Ufd ufd(n_nodes);
-    std::vector<std::unordered_set<int64_t>> mtx(n_nodes);
-    auto have_mutex = [&](int64_t ra, int64_t rb) {
-        const auto& small = mtx[ra].size() < mtx[rb].size() ? mtx[ra] : mtx[rb];
-        int64_t other = (&small == &mtx[ra]) ? rb : ra;
-        return small.count(other) > 0;
+    std::vector<int32_t> parent(n_nodes), size(n_nodes, 1);
+    std::iota(parent.begin(), parent.end(), 0);
+    auto find = [&](int32_t x) {
+        while (parent[x] != x) {
+            parent[x] = parent[parent[x]];
+            x = parent[x];
+        }
+        return x;
+    };
+    struct List {
+        int64_t begin = 0;
+        int32_t size = 0, cap = 0;
+    };
+    std::vector<List> list(n_nodes);
+    std::vector<int32_t> pool;
+    std::vector<std::vector<int64_t>> released(32);  // by log2 of capacity
+    auto release = [&](const List& l) {
+        if (l.cap > 0) released[__builtin_ctz(l.cap)].push_back(l.begin);
+    };
+    auto tidy = [&](List& l) {
+        int32_t* p = pool.data() + l.begin;
+        for (int32_t k = 0; k < l.size; ++k) p[k] = find(p[k]);
+        std::sort(p, p + l.size);
+        l.size = static_cast<int32_t>(std::unique(p, p + l.size) - p);
+    };
+    // room for ``need`` entries in ``l``
+    auto reserve = [&](List& l, int32_t need) {
+        if (need <= l.cap) return;
+        if (l.size >= 8) {
+            int32_t before = l.size;
+            tidy(l);
+            need -= before - l.size;
+            if (need <= l.cap && 2 * l.size <= l.cap) return;
+        }
+        int32_t cap = std::max<int32_t>(4, 2 * l.cap);
+        while (cap < need) cap *= 2;
+        auto& free_ranges = released[__builtin_ctz(cap)];
+        int64_t b;
+        if (free_ranges.empty()) {
+            b = static_cast<int64_t>(pool.size());
+            pool.resize(b + cap);
+        } else {
+            b = free_ranges.back();
+            free_ranges.pop_back();
+        }
+        std::copy(pool.begin() + l.begin, pool.begin() + l.begin + l.size,
+                  pool.begin() + b);
+        release(l);
+        l.begin = b;
+        l.cap = cap;
+    };
+    auto separated = [&](int32_t ra, int32_t rb) {
+        const List* a = &list[ra];
+        const List* b = &list[rb];
+        if (a->size == 0 || b->size == 0) return false;
+        if (a->size > b->size) {
+            std::swap(a, b);
+            rb = ra;
+        }
+        const int32_t* p = pool.data() + a->begin;
+        for (int32_t k = 0; k < a->size; ++k) {
+            if (find(p[k]) == rb) return true;
+        }
+        return false;
     };
     for (int64_t i = 0; i < n_edges; ++i) {
-        if (u[i] < 0) continue;
-        int64_t ru = ufd.find(u[i]), rv = ufd.find(v[i]);
+        const int32_t vp = v_packed[i];
+        if (u[i] < 0 || ((vp >> 29) & 1)) continue;
+        int32_t ru = find(u[i]), rv = find(vp & ((1 << 29) - 1));
         if (ru == rv) continue;
-        if (mutex_flag[i]) {
-            mtx[ru].insert(rv);
-            mtx[rv].insert(ru);
-        } else {
-            if (have_mutex(ru, rv)) continue;
-            int64_t keep = ufd.merge(ru, rv);
-            int64_t gone = keep == ru ? rv : ru;
-            // same rewiring discipline as mws_clustering above (no
-            // small-into-large swap: it breaks back-pointer symmetry)
-            for (int64_t c : mtx[gone]) {
-                mtx[c].erase(gone);
-                if (c != keep) {
-                    mtx[c].insert(keep);
-                    mtx[keep].insert(c);
-                }
+        if ((vp >> 30) & 1) {
+            for (auto [r, partner] : {std::pair{ru, rv}, std::pair{rv, ru}}) {
+                List& l = list[r];
+                reserve(l, l.size + 1);
+                pool[l.begin + l.size++] = partner;
             }
-            mtx[gone].clear();
+            continue;
         }
+        if (separated(ru, rv)) continue;
+        if (size[ru] < size[rv]) std::swap(ru, rv);
+        parent[rv] = ru;
+        size[ru] += size[rv];
+        List& keep = list[ru];
+        List& gone = list[rv];
+        if (gone.size == 0) {
+            release(gone);
+            gone = List{};
+            continue;
+        }
+        if (keep.size < gone.size) std::swap(keep, gone);
+        reserve(keep, keep.size + gone.size);
+        std::copy(pool.begin() + gone.begin,
+                  pool.begin() + gone.begin + gone.size,
+                  pool.begin() + keep.begin + keep.size);
+        keep.size += gone.size;
+        release(gone);
+        gone = List{};
     }
-    std::unordered_map<int64_t, uint64_t> remap;
-    uint64_t next = 0;
+    // labels numbered by each cluster's first voxel, as mws_clustering's
+    std::vector<int64_t> label_of(n_nodes, -1);
+    int64_t next = 0;
     for (int64_t i = 0; i < n_nodes; ++i) {
-        int64_t r = ufd.find(i);
-        auto it = remap.find(r);
-        if (it == remap.end()) it = remap.emplace(r, next++).first;
-        labels_out[i] = it->second;
+        int32_t r = find(static_cast<int32_t>(i));
+        if (label_of[r] < 0) label_of[r] = next++;
+        labels_out[i] = static_cast<uint64_t>(label_of[r]);
     }
-    return static_cast<int64_t>(next);
+    return next;
 }
 
 // ---------------------------------------------------------------------------

@@ -220,12 +220,12 @@ def grid_graph_edges(affs: np.ndarray, offsets: Sequence[Sequence[int]],
             cat_uv(uvm), np.concatenate(wm) if wm else np.zeros(0))
 
 
-@partial(jax.jit, static_argnames=("offsets", "strides", "seeded"))
+@partial(jax.jit, static_argnames=("offsets", "strides"))
 def _sorted_edges_device(affs, seeds, offsets: Tuple[Tuple[int, ...], ...],
-                         strides: Tuple[int, ...], seeded: bool):
+                         strides: Tuple[int, ...]):
     """Extract ALL grid edges and sort them by DESCENDING mutex-watershed
     priority on device, returning (u, v_packed) int32 streams the host
-    union-find scan consumes directly (native.mutex_clustering_sorted).
+    union-find scan consumes directly (native.mutex_clustering_packed).
 
     The host Kruskal's dominant cost is its stable_sort of tens of
     millions of 24-byte edge structs; the device does that sort as one
@@ -234,14 +234,27 @@ def _sorted_edges_device(affs, seeds, offsets: Tuple[Tuple[int, ...], ...],
     dropped (zero-affinity attractive or off-stride mutex; kept in the
     stream so the layout is static, skipped by the scan via u = -1).
 
-    ``seeds`` (int32 volume, 0 = unseeded) boost intra-seed attractive
-    edges above every data weight (the two-pass seeded variant); pass a
-    dummy scalar array when ``seeded`` is False.
+    ``seeds`` (int32 volume of the block's shape, 0 = unseeded) boost
+    intra-seed attractive edges above every data weight (the two-pass
+    seeded variant); an unseeded block passes zeros, so both passes run
+    one program.  Scopes ``mws_edges`` and ``mws_sort`` name the two
+    stages in a profiler trace.
     """
+    with jax.named_scope("mws_edges"):
+        key, u_s, v_packed = _edge_stream(affs, seeds, offsets, strides)
+    with jax.named_scope("mws_sort"):
+        _, u_sorted, vp_sorted = jax.lax.sort(
+            [key, u_s, v_packed], num_keys=1, is_stable=True)
+    return u_sorted, vp_sorted
+
+
+def _edge_stream(affs, seeds, offsets, strides):
+    """(sort key, u, v_packed) of every grid edge, in (channel, anchor)
+    order; seed equality is read through the same static slices as the
+    affinities, not gathered per edge."""
     shape = affs.shape[1:]
     ndim = len(shape)
     flat = jnp.arange(int(np.prod(shape)), dtype=jnp.int32).reshape(shape)
-    sflat = seeds.reshape(-1) if seeded else None
     us, vs, ws, ms, oks = [], [], [], [], []
     for c, off in enumerate(offsets):
         sl_a, sl_b = _offset_slices(off, shape)
@@ -263,9 +276,9 @@ def _sorted_edges_device(affs, seeds, offsets: Tuple[Tuple[int, ...], ...],
                     on_grid &= sel.reshape(shp)
                 valid &= on_grid.reshape(-1)
         else:
-            if seeded:
-                su, sv = sflat[u], sflat[v]
-                w = jnp.where((su != 0) & (su == sv), jnp.float32(2.0), w)
+            su = seeds[sl_a].reshape(-1)
+            sv = seeds[sl_b].reshape(-1)
+            w = jnp.where((su != 0) & (su == sv), jnp.float32(2.0), w)
             # zero-affinity attractive edges carry no merge evidence
             # (deliberate deviation from affogato, see
             # mutex_watershed_segmentation)
@@ -286,22 +299,18 @@ def _sorted_edges_device(affs, seeds, offsets: Tuple[Tuple[int, ...], ...],
     v_packed = (v_all
                 | (m_all.astype(jnp.int32) << 30)
                 | ((~ok_all).astype(jnp.int32) << 29))
-    _, u_sorted, vp_sorted = jax.lax.sort(
-        [key, u_s, v_packed], num_keys=1, is_stable=True)
-    return u_sorted, vp_sorted
+    return key, u_s, v_packed
 
 
-@partial(jax.jit, static_argnames=("outer_shape", "offsets", "strides",
-                                   "seeded"))
+@partial(jax.jit, static_argnames=("outer_shape", "offsets", "strides"))
 def _sorted_edges_resident_impl(vol, origin, seeds,
                                 outer_shape: Tuple[int, ...],
                                 offsets: Tuple[Tuple[int, ...], ...],
-                                strides: Tuple[int, ...], seeded: bool):
+                                strides: Tuple[int, ...]):
     affs = jax.lax.dynamic_slice(
         vol, (0,) + tuple(origin[d] for d in range(len(outer_shape))),
         (vol.shape[0],) + outer_shape)
-    u_sorted, vp_sorted = _sorted_edges_device(affs, seeds, offsets,
-                                               strides, seeded)
+    u_sorted, vp_sorted = _sorted_edges_device(affs, seeds, offsets, strides)
     return u_sorted, vp_sorted, affs.sum()
 
 
@@ -349,14 +358,13 @@ def _sorted_edges_resident(affs_dev, origin, outer_shape,
             f"outer block {tuple(outer_shape)} has >= 2^29 voxels — the "
             "packed edge stream cannot address it; use the host path or "
             "shrink blocks")
-    seeded = seeds is not None
+    outer_shape = tuple(int(s) for s in outer_shape)
     seeds_in = (jnp.asarray(compact_seeds_int32(seeds))
-                if seeded else jnp.zeros((1,) * len(outer_shape), jnp.int32))
+                if seeds is not None else jnp.zeros(outer_shape, jnp.int32))
     return _sorted_edges_resident_impl(
         affs_dev, jnp.asarray(origin, dtype=jnp.int32), seeds_in,
-        tuple(int(s) for s in outer_shape),
-        tuple(tuple(int(o) for o in off) for off in offsets),
-        tuple(int(s) for s in strides), seeded)
+        outer_shape, tuple(tuple(int(o) for o in off) for off in offsets),
+        tuple(int(s) for s in strides))
 
 
 def mutex_watershed_scan_sorted(u, vp, shape,
@@ -368,17 +376,12 @@ def mutex_watershed_scan_sorted(u, vp, shape,
     sequential host scan (``host-scan``) to separate stages — lumping
     both under a ``sync-`` stage mis-credited the host scan to the
     accelerator path (ADVICE r5)."""
-    dropped = (vp >> 29) & 1
-    u = np.where(dropped != 0, np.int32(-1), u)
-    v = vp & np.int32((1 << 29) - 1)
-    flags = ((vp >> 30) & 1).astype(np.uint8)
     n_nodes = int(np.prod(shape))
-    cluster = native.mutex_clustering_sorted(n_nodes, u, v, flags)
-    labels = cluster.reshape(shape)
-    if mask is not None:
-        labels = np.where(mask, labels + 1, 0)
-    else:
-        labels = labels + 1
+    cluster = native.mutex_clustering_packed(n_nodes, u, vp).reshape(shape)
+    if mask is None:
+        # the scan numbers its clusters 0, 1, ...: already consecutive
+        return cluster + np.uint64(1)
+    labels = np.where(mask, cluster + 1, 0)
     uniq, inv = np.unique(labels, return_inverse=True)
     if uniq.size and uniq[0] == 0:
         labels = inv.reshape(shape).astype("uint64")

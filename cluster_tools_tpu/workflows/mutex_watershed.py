@@ -23,6 +23,8 @@ C++ (native.mutex_clustering).
 from __future__ import annotations
 
 import os
+import threading
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
@@ -123,14 +125,27 @@ class MwsBlocksBase(BlockTask):
             colors = Blocking(shape, block_shape).checkerboard()
             allowed = set(block_list)
             block_list = [b for b in colors[self.pass_id] if b in allowed]
-        self.run_jobs(block_list, {
-            "input_path": self.input_path, "input_key": self.input_key,
-            "output_path": self.output_path, "output_key": self.output_key,
-            "offsets": self.offsets, "halo": self.halo,
-            "mask_path": self.mask_path, "mask_key": self.mask_key,
-            "shape": shape, "block_shape": block_shape,
-            "seeded": self.seeded, "global_max": global_max,
-        }, n_jobs=self.max_jobs)
+        if self.pass_id != 1:
+            # a run reads, normalizes and uploads its own input: a volume
+            # rewritten at the same path is never served from the cache
+            _AFFS_DEV_CACHE.clear()
+        try:
+            # on the tpu target one job takes the whole pass, so that the
+            # device path overlaps its blocks' host scans (the inline
+            # executor runs jobs one after another)
+            self.run_jobs(block_list, {
+                "input_path": self.input_path, "input_key": self.input_key,
+                "output_path": self.output_path,
+                "output_key": self.output_key,
+                "offsets": self.offsets, "halo": self.halo,
+                "mask_path": self.mask_path, "mask_key": self.mask_key,
+                "shape": shape, "block_shape": block_shape,
+                "seeded": self.seeded, "global_max": global_max,
+            }, n_jobs=1 if self.target == "tpu" else self.max_jobs)
+        finally:
+            if self.pass_id != 0:
+                # the run's last pass: release the resident volume
+                _AFFS_DEV_CACHE.clear()
 
     @classmethod
     def process_job(cls, job_id: int, job_config: Dict[str, Any], log_fn):
@@ -261,17 +276,21 @@ class MwsBlocksBase(BlockTask):
     def _process_device_sorted(cls, job_config, log_fn, blocking, ds_in,
                                ds_out, cfg):
         """Resident device-sort pipeline: the affinity volume uploads ONCE
-        (kept on device across the pass-1/pass-2 tasks of one driver
-        process), each block's program dynamic-slices its outer window,
-        extracts every grid edge and sorts them by descending priority on
-        device (ops/mws._sorted_edges_device — the host Kruskal's
-        stable_sort of 24-byte edge structs was ~60% of each block), and
-        the host runs only the sequential union-find scan — on block i
-        while the device sorts block i+1 (the r3 hybrid-pipeline
-        pattern)."""
+        (kept on device across the pass-1/pass-2 tasks of one run), each
+        block's program dynamic-slices its outer window, extracts every
+        grid edge and sorts them by descending priority on device
+        (ops/mws._sorted_edges_device — the host Kruskal's stable_sort of
+        24-byte edge structs was ~60% of each block).  The host runs only
+        the sequential union-find scan.  The main thread enqueues block
+        i+1's sort, waits for block i's, downloads its stream (so at most
+        two streams are on the device) and hands the scan, compaction,
+        write and seed pairs to a thread pool: the scans of the job's
+        blocks overlap each other and the device sorts, as many at once
+        as the host's cores and memory hold (:func:`_scan_workers`).
+        Each block's outputs are those of a serial run, byte for byte."""
         import jax.numpy as jnp
 
-        from ..core.runtime import stage, stage_bytes
+        from ..core.runtime import stage, stage_add, stage_bytes
         from ..ops.mws import (mutex_watershed_scan_sorted,
                                _sorted_edges_resident)
 
@@ -281,17 +300,16 @@ class MwsBlocksBase(BlockTask):
         strides = tuple(int(s)
                         for s in (cfg.get("strides") or [1, 1, 1]))
         key = (os.path.abspath(cfg["input_path"]), cfg["input_key"])
-        ent = _AFFS_DEV_CACHE.get(key)
-        if ent is None:
+        affs_dev = _AFFS_DEV_CACHE.get(key)
+        if affs_dev is None:
             with stage("store-read"):
                 affs_host = normalize(ds_in[...])
             with stage("h2d-upload"):
                 affs_dev = jnp.asarray(affs_host)
             stage_bytes("h2d-upload", affs_host.nbytes)
+            del affs_host
             _AFFS_DEV_CACHE.clear()   # one resident volume at a time
             _AFFS_DEV_CACHE[key] = affs_dev
-        else:
-            affs_dev = ent
 
         outer_shape_of = {}
         block_meta = {}
@@ -331,25 +349,31 @@ class MwsBlocksBase(BlockTask):
                     outer_shape_of[block_id], offsets, strides, seeds)
             return handles, seeds
 
-        def drain(block_id, handles, seeds):
-            outer_bb, inner_bb, local_bb = block_meta[block_id]
-            shape_o = outer_shape_of[block_id]
-            # three separately-attributed phases: the wait for the device
-            # sort (sync-execute), the edge-stream download (d2h-edges),
-            # and the sequential host C++ union-find scan (host-scan) —
-            # previously one 'sync-meta' stage that credited the host
-            # scan to the accelerator path (ADVICE r5)
+        def fetch(handles):
+            """The block's sorted stream on the host, None for an all-zero
+            block: the wait for the device sort (sync-execute), then the
+            download (d2h-edges)."""
             with stage("sync-execute"):
                 asum = float(np.asarray(handles[2]))
             if asum == 0.0:
-                log_fn(f"processed block {block_id}")
-                return
+                return None
             with stage("d2h-edges"):
                 u = np.asarray(handles[0])
                 vp = np.asarray(handles[1])
             stage_bytes("d2h-edges", u.nbytes + vp.nbytes)
+            return u, vp
+
+        def finish(block_id, stream, seeds):
+            if stream is None:
+                log_fn(f"processed block {block_id}")
+                return
+            _, inner_bb, local_bb = block_meta[block_id]
+            u, vp = stream
             with stage("host-scan"):
-                seg = mutex_watershed_scan_sorted(u, vp, shape_o)
+                seg = mutex_watershed_scan_sorted(u, vp,
+                                                  outer_shape_of[block_id])
+            # count only: the edges the scan consumed (dropped ones are -1)
+            stage_add("scan-edges", 0.0, count=int(np.count_nonzero(u >= 0)))
             nonzero = np.unique(seg[seg > 0])
             if len(nonzero) >= offset_unit:
                 raise RuntimeError(
@@ -375,19 +399,75 @@ class MwsBlocksBase(BlockTask):
                     pairs)
             log_fn(f"processed block {block_id}")
 
-        pending = None
-        for block_id in job_config["block_list"]:
-            handles, seeds = submit(block_id)
+        block_list = job_config["block_list"]
+        n_nodes = max((int(np.prod(s)) for s in outer_shape_of.values()),
+                      default=0)
+        workers = _scan_workers(len(block_list), n_nodes,
+                                n_nodes * len(offsets))
+        # a slot per stream on the host: the main thread takes one before
+        # it downloads a stream, the scan gives it back when it is done
+        slots = threading.BoundedSemaphore(workers)
+
+        def scan(block_id, stream, seeds):
+            try:
+                finish(block_id, stream, seeds)
+            finally:
+                slots.release()
+
+        def hand_over(pool, block_id, handles, seeds):
+            slots.acquire()
+            try:
+                stream = fetch(handles)
+            except BaseException:
+                slots.release()
+                raise
+            return pool.submit(scan, block_id, stream, seeds)
+
+        with ThreadPoolExecutor(workers) as pool:
+            futures, pending = [], None
+            for block_id in block_list:
+                # the device sorts this block while the last one comes down
+                handles, seeds = submit(block_id)
+                if pending is not None:
+                    futures.append(hand_over(pool, *pending))
+                pending = (block_id, handles, seeds)
             if pending is not None:
-                drain(*pending)
-            pending = (block_id, handles, seeds)
-        if pending is not None:
-            drain(*pending)
+                futures.append(hand_over(pool, *pending))
+            pending = None
+            for f in futures:
+                f.result()
+
+
+def _host_available_bytes() -> int:
+    """Memory the host can still give (``MemAvailable``; free pages where
+    ``/proc/meminfo`` is missing)."""
+    try:
+        with open("/proc/meminfo") as f:
+            for line in f:
+                if line.startswith("MemAvailable:"):
+                    return int(line.split()[1]) * 1024
+    except OSError:
+        pass
+    return os.sysconf("SC_AVPHYS_PAGES") * os.sysconf("SC_PAGE_SIZE")
+
+
+def _scan_workers(n_blocks: int, n_nodes: int, n_edges: int) -> int:
+    """How many block scans run at once: one per block, less one core for
+    the main thread, and no more than the host's available memory holds
+    at ~16 bytes per edge (the stream, the mutex lists) and ~96 per voxel
+    (union-find, labels and their compaction): 4.6 GB for a (54, 544,
+    544) block of 12 channels."""
+    cores = len(os.sched_getaffinity(0)) if hasattr(
+        os, "sched_getaffinity") else (os.cpu_count() or 1)
+    per_scan = 16 * n_edges + 96 * n_nodes
+    by_memory = _host_available_bytes() // max(per_scan, 1)
+    return int(max(1, min(n_blocks, cores - 1, by_memory)))
 
 
 #: device-resident normalized affinity volume, shared by the pass-1 and
-#: pass-2 tasks of one driver process (~0.4 GB for the bench instance;
-#: cleared when a different volume arrives)
+#: pass-2 tasks of one run (5.0 GB of float32 for 12 channels of 100 x
+#: 1024 x 1024); filled by the run's first block, cleared when its first
+#: pass starts and when its last pass ends (``MwsBlocksBase.run_impl``)
 _AFFS_DEV_CACHE: Dict = {}
 
 

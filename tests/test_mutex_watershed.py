@@ -217,7 +217,7 @@ def test_grid_graph_edges_host_matches_device():
 
 
 def test_device_sorted_mws_matches_host():
-    """The device extract+sort path (mutex_clustering_sorted over the
+    """The device extract+sort path (mutex_clustering_packed over the
     pre-sorted stream) must reproduce the host path's partition exactly
     (same priorities, same tie order, same zero-affinity drops)."""
     import jax.numpy as jnp
