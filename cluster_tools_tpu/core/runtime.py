@@ -1135,6 +1135,13 @@ EXECUTORS = {
 }
 
 
+def jobs_run_in_driver(target: str) -> bool:
+    """Whether ``target``'s executor runs a task's jobs one after another
+    in the driver process: the one place where a single job may own state
+    across every block of a task, such as a running id offset."""
+    return EXECUTORS.get(target) is _InlineExecutor
+
+
 # ---------------------------------------------------------------------------
 # BlockTask
 # ---------------------------------------------------------------------------
@@ -1162,6 +1169,10 @@ class BlockTask(Task):
     allow_retry: bool = True
     #: tasks that run as a single global job (reference: cluster_tasks.py:335-341)
     global_task: bool = False
+    #: a lone job resumes from what it recorded per block, so its failure
+    #: is retried over the blocks it did not log, not taken as a majority
+    #: of the jobs failing
+    single_job_resumes: bool = False
     #: retry attempt counter (class default so run_jobs() works when called
     #: directly, without going through run())
     _retry_count: int = 0
@@ -1372,7 +1383,8 @@ class BlockTask(Task):
 
         # majority-of-jobs-failed heuristic: fundamentally broken, don't retry
         # (reference: cluster_tasks.py:127-134)
-        if len(failed_jobs) > n_jobs / 2:
+        if len(failed_jobs) > n_jobs / 2 and not (
+                n_jobs == 1 and self.single_job_resumes):
             self._fail(failed_jobs)
 
         processed: Set[int] = set()

@@ -86,6 +86,8 @@ STAGE_REGISTRY = {
     "mesh-fallback",
     # count only: edges a mutex-watershed host scan consumed
     "scan-edges",
+    # count only: blocks a single-pass watershed wrote with their final ids
+    "final-ids",
     # pool-worker fetches, overlapped with the main thread's sync-execute
     # waits (fetch- rather than d2h-: the copies were started at submit)
     "fetch-dense", "fetch-rle",

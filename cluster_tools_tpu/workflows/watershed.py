@@ -16,13 +16,14 @@ Python (watershed.py:211-230); here it is one batched device call.
 from __future__ import annotations
 
 import os
+import shutil
 from functools import lru_cache
 from typing import Any, Dict, Optional
 
 import numpy as np
 
 from ..core.blocking import Blocking
-from ..core.runtime import BlockTask, stage
+from ..core.runtime import BlockTask, jobs_run_in_driver, stage, stage_add
 from ..core.storage import file_reader
 from ..core.workflow import Task
 from .relabel import RelabelWorkflow
@@ -593,12 +594,66 @@ def run_ws_block_seeded(data: np.ndarray, cfg: Dict[str, Any],
     return out
 
 
+def _ids_folder(tmp_folder: str, task_name: str) -> str:
+    return os.path.join(tmp_folder, f"{task_name}_ids")
+
+
+class _FinalIds:
+    """The running id offset of a single-pass watershed job that visits
+    its blocks in id order: a block's ids follow every id of the blocks
+    before it, which is the order a volume-wide relabel of block-offset
+    ids gives them.  Each written block's id count is recorded in
+    ``folder``, so a retried job starts from the counts of the blocks an
+    earlier attempt wrote and writes the ids an unbroken run would have."""
+
+    def __init__(self, folder: str, job_blocks):
+        os.makedirs(folder, exist_ok=True)
+        job = set(job_blocks)
+        earlier = []
+        for name in os.listdir(folder):
+            if name.isdigit() and int(name) not in job:
+                with open(os.path.join(folder, name)) as f:
+                    earlier.append((int(name), int(f.read())))
+        self.folder = folder
+        self.earlier = sorted(earlier)
+        self.offset = 0
+        self.last = -1
+        self._next = 0
+
+    def _add_earlier(self, before: int) -> None:
+        while (self._next < len(self.earlier)
+               and self.earlier[self._next][0] < before):
+            self.offset += self.earlier[self._next][1]
+            self._next += 1
+
+    def offset_before(self, block_id: int) -> int:
+        if block_id <= self.last:
+            raise ValueError(
+                f"block {block_id} after block {self.last}: final ids "
+                "need the blocks in ascending id order")
+        self.last = block_id
+        self._add_earlier(block_id)
+        return self.offset
+
+    def record(self, block_id: int, count: int) -> None:
+        self.offset += count
+        part = os.path.join(self.folder, f"{block_id}.part")
+        with open(part, "w") as f:
+            f.write(str(count))
+        os.replace(part, os.path.join(self.folder, str(block_id)))
+
+    def total(self) -> int:
+        return self.offset + sum(k for _, k in self.earlier[self._next:])
+
+
 class WatershedTask(BlockTask):
     """Blockwise DT watershed (reference: WatershedBase, watershed.py:34-110).
 
-    Labels are made globally unique by offsetting with
-    ``block_id * prod(block_shape)`` (reference: watershed.py:307); chain
-    RelabelWorkflow (or use WatershedWorkflow) to compact them.
+    Each block's ids are compacted to ``1..k`` and made globally unique by
+    offsetting with ``block_id * prod(block_shape)`` (reference:
+    watershed.py:307); chain RelabelWorkflow (or use WatershedWorkflow) to
+    make them consecutive.  :class:`WatershedFinalIdsTask` writes the
+    consecutive ids directly.
 
     ``pass_id``/``seeded`` implement the checkerboard two-pass variant
     (reference: two_pass_watershed.py:60-94): color-0 blocks run the plain
@@ -611,6 +666,9 @@ class WatershedTask(BlockTask):
     #: None = all blocks (single pass); 0/1 = checkerboard color
     pass_id: Optional[int] = None
     seeded: bool = False
+    #: offset each block by the id counts of the blocks before it (one job
+    #: in block-id order) in place of ``block_id * prod(block_shape)``
+    final_ids: bool = False
 
     def __init__(self, input_path: str, input_key: str, output_path: str,
                  output_key: str, mask_path: str = "", mask_key: str = "", **kw):
@@ -644,20 +702,47 @@ class WatershedTask(BlockTask):
             f.require_dataset(self.output_key, shape=shape, chunks=block_shape,
                               dtype="uint64")
         block_list = self.blocks_in_volume(shape, block_shape)
+        n_jobs = self.max_jobs
         if self.pass_id is not None:
             colors = Blocking(shape, block_shape).checkerboard()
             allowed = set(block_list)
             block_list = [b for b in colors[self.pass_id] if b in allowed]
+        if self.final_ids:
+            if not jobs_run_in_driver(self.target):
+                raise ValueError(
+                    f"target {self.target!r} runs jobs apart from the "
+                    "driver: no one job can own the running id offset")
+            block_list, n_jobs = sorted(block_list), 1
+            from ..parallel import multihost as mh
+
+            if mh.is_lead():  # the lead runs the job; peers only wait
+                shutil.rmtree(_ids_folder(self.tmp_folder, self.name_with_id),
+                              ignore_errors=True)
         self.run_jobs(block_list, {
             "input_path": self.input_path, "input_key": self.input_key,
             "output_path": self.output_path, "output_key": self.output_key,
             "mask_path": self.mask_path, "mask_key": self.mask_key,
             "shape": shape, "block_shape": block_shape,
             "seeded": self.seeded,
-        }, n_jobs=self.max_jobs)
+        }, n_jobs=n_jobs)
 
     @classmethod
     def process_job(cls, job_id: int, job_config: Dict[str, Any], log_fn):
+        ids = None
+        if cls.final_ids:
+            ids = _FinalIds(_ids_folder(job_config["tmp_folder"],
+                                        job_config["task_name"]),
+                            job_config["block_list"])
+        cls._process_blocks(job_config, log_fn, ids)
+        if ids is not None:
+            # what WriteAssignments records after a relabel
+            cfg = job_config["config"]
+            with file_reader(cfg["output_path"]) as f:
+                f[cfg["output_key"]].attrs["maxId"] = ids.total()
+
+    @classmethod
+    def _process_blocks(cls, job_config: Dict[str, Any], log_fn,
+                        ids: Optional[_FinalIds]) -> None:
         cfg = job_config["config"]
         blocking = Blocking(cfg["shape"], cfg["block_shape"])
         halo = cfg.get("halo") or [0] * blocking.ndim
@@ -686,7 +771,7 @@ class WatershedTask(BlockTask):
             inner_sl = tuple(slice(h, h + (b.stop - b.start))
                              for h, b in zip(halo, block.bb))
             inner = ws[inner_sl]
-            # compact to 1..k (k <= inner voxel count < offset unit), THEN
+            # compact to 1..k (k <= inner voxel count <= offset unit), THEN
             # offset for global uniqueness (reference: watershed.py:307) —
             # uncompacted CC root indices range over the larger outer block
             # and would collide across blocks
@@ -695,11 +780,15 @@ class WatershedTask(BlockTask):
                 compact = np.searchsorted(nonzero, inner).astype(
                     "uint64") + 1
                 compact[inner == 0] = 0
-                compact = np.where(
-                    compact > 0,
-                    compact + np.uint64(block_id) * label_offset_unit, 0)
+                offset = (np.uint64(block_id) * label_offset_unit
+                          if ids is None
+                          else np.uint64(ids.offset_before(block_id)))
+                compact = np.where(compact > 0, compact + offset, 0)
             with stage("store-write"):
                 ds_out[block.bb] = compact
+            if ids is not None:
+                ids.record(block_id, nonzero.size)
+                stage_add("final-ids", 0.0)
             log_fn(f"processed block {block_id}")
 
         def _read_block(block_id: int) -> np.ndarray:
@@ -861,6 +950,19 @@ class WatershedTask(BlockTask):
             else:
                 ws = run_ws_block(data, cfg, bmask)
             _write_result(block_id, ws)
+
+
+class WatershedFinalIdsTask(WatershedTask):
+    """Single pass that writes each fragment once with its final id: one
+    job visits the blocks in id order and offsets each block's ids by the
+    counts of the blocks before it — the ids RelabelWorkflow would give
+    block-offset ids, with no read-back or rewrite — and records ``maxId``.
+    Needs a target whose executor runs jobs in order in the driver
+    process (``runtime.jobs_run_in_driver``); a failed job resumes from
+    the counts it recorded."""
+
+    final_ids = True
+    single_job_resumes = True
 
 
 class WatershedPass1Task(WatershedTask):
@@ -1084,7 +1186,14 @@ class AgglomerateTask(BlockTask):
 
 class WatershedWorkflow(Task):
     """[TwoPass]Watershed -> [Agglomerate] -> RelabelWorkflow (reference:
-    watershed/watershed_workflow.py:20-60)."""
+    watershed/watershed_workflow.py:20-60).
+
+    A single pass without agglomeration, on a target whose jobs run in
+    order in the driver process (``inline``, ``tpu``, ``mesh``), is
+    :class:`WatershedFinalIdsTask` alone: it writes the ids the relabel
+    would.  Other targets, two-pass and agglomeration keep the per-block
+    offsets and the relabel: their jobs share no process, or other tasks
+    re-offset the ids."""
 
     def __init__(self, input_path: str, input_key: str, output_path: str,
                  output_key: str, tmp_folder: str, config_dir: str,
@@ -1110,6 +1219,8 @@ class WatershedWorkflow(Task):
         self.max_jobs = max_jobs
         self.target = target
         self.dependency = dependency
+        self.final_ids = (not two_pass and not agglomeration
+                          and jobs_run_in_driver(target))
         super().__init__()
 
     def requires(self):
@@ -1119,6 +1230,9 @@ class WatershedWorkflow(Task):
             input_path=self.input_path, input_key=self.input_key,
             output_path=self.output_path, output_key=self.output_key,
             mask_path=self.mask_path, mask_key=self.mask_key)
+        if self.final_ids:
+            return WatershedFinalIdsTask(dependency=self.dependency,
+                                         **ws_kwargs, **common)
         if self.two_pass:
             p1 = WatershedPass1Task(dependency=self.dependency, **ws_kwargs,
                                     **common)
@@ -1144,5 +1258,6 @@ class WatershedWorkflow(Task):
     def output(self):
         from ..core.workflow import FileTarget
 
-        return FileTarget(os.path.join(self.tmp_folder,
-                                       "write_relabel_ws.status"))
+        name = (WatershedFinalIdsTask.task_name if self.final_ids
+                else "write_relabel_ws")
+        return FileTarget(os.path.join(self.tmp_folder, f"{name}.status"))

@@ -421,12 +421,16 @@ def test_host_watershed_block_quality():
     assert (host_m[:, :, 12:] == 0).all()
 
 
-def test_watershed_workflow_records_host_stages(tmp_workdir, tmp_path):
-    """The streamed watershed task and the relabel passes time their host
-    work as stages (and so as ``ctt.stage.*`` spans in a profiler trace):
-    block reads on the prefetch thread, the wait for them, the device
-    waits, the relabel map and the fragment writes; FindUniques its reads
-    and scans, FindLabeling its scan."""
+@pytest.mark.parametrize("target", ["inline", "threads"])
+def test_watershed_workflow_records_host_stages(tmp_workdir, tmp_path,
+                                                target):
+    """The streamed watershed task times its host work as stages (and so
+    as ``ctt.stage.*`` spans in a profiler trace): block reads on the
+    prefetch thread, the wait for them, the device waits, the per-block
+    relabel map and the fragment writes.  Where the jobs run in the
+    driver (``inline``) it writes every block with its final id and no
+    relabel follows; elsewhere (``threads``) the relabel passes do, with
+    FindUniques timing its reads and scans and FindLabeling its scan."""
     import glob
     import json
     import os
@@ -440,7 +444,7 @@ def test_watershed_workflow_records_host_stages(tmp_workdir, tmp_path):
     assert build([WatershedWorkflow(
         input_path=path, input_key="boundaries", output_path=path,
         output_key="ws", tmp_folder=tmp_folder, config_dir=config_dir,
-        max_jobs=1, target="inline")], raise_on_failure=True)
+        max_jobs=1, target=target)], raise_on_failure=True)
     stages = {}
     for sf in glob.glob(os.path.join(tmp_folder, "*.status")):
         with open(sf) as f:
@@ -450,5 +454,126 @@ def test_watershed_workflow_records_host_stages(tmp_workdir, tmp_path):
             "store-write"} <= set(stages["watershed"]), stages
     # one fragment write per block of the conftest's [10, 10, 10] grid
     assert stages["watershed"]["store-write"] == 27
-    assert {"store-read", "host-scan"} <= set(stages["find_uniques"])
-    assert "host-scan" in stages["find_labeling"]
+    if target == "inline":
+        assert stages["watershed"]["final-ids"] == 27
+        assert set(stages) == {"watershed"}, stages
+    else:
+        assert "final-ids" not in stages["watershed"]
+        assert {"store-read", "host-scan"} <= set(stages["find_uniques"])
+        assert "host-scan" in stages["find_labeling"]
+
+
+def _ws_case(tmp_path, case):
+    """Input, configuration and mask of one byte-identity case: a config
+    dir, the input path and the workflow's mask kwargs."""
+    import json
+
+    from cluster_tools_tpu.core.config import ConfigDir
+
+    gconf = {"block_shape": [10, 10, 10], "max_num_retries": 0}
+    tconf = {"halo": [2, 4, 4], "sigma_seeds": 1.0, "size_filter": 4}
+    shape = (24, 24, 24)
+    mask_kw = {}
+    if case == "ws_2d":
+        shape = (8, 24, 24)
+        gconf["block_shape"] = [4, 12, 12]
+        tconf.update(apply_dt_2d=True, apply_ws_2d=True, halo=[0, 2, 2],
+                     sigma_weights=1.0)
+    elif case == "roi":
+        gconf.update(roi_begin=[10, 0, 10], roi_end=[24, 20, 24])
+    elif case == "block_list":
+        blocks = str(tmp_path / "blocks.json")
+        with open(blocks, "w") as f:
+            json.dump([26, 3, 14, 0, 9, 21, 5, 17], f)
+        gconf["block_list_path"] = blocks
+    vol = _boundary_volume(shape, n_cells=8, seed=3)
+    path = str(tmp_path / "in.n5")
+    with file_reader(path) as f:
+        f.require_dataset("b", shape=shape, chunks=(12, 12, 12),
+                          dtype="float32")[...] = vol
+        if case == "mask":
+            # block 0's whole outer window is background: the block is
+            # skipped, and its neighbours are partly masked
+            mask = np.ones(shape, "uint8")
+            mask[:12, :12, :12] = 0
+            f.require_dataset("m", shape=shape, chunks=(12, 12, 12),
+                              dtype="uint8")[...] = mask
+            mask_kw = {"mask_path": path, "mask_key": "m"}
+    config_dir = str(tmp_path / "configs")
+    cd = ConfigDir(config_dir)
+    cd.write_global_config(gconf)
+    cd.write_task_config("watershed", tconf)
+    return config_dir, path, mask_kw
+
+
+def _run_ws(tmp_path, config_dir, path, mask_kw, target, key):
+    """One WatershedWorkflow into ``key``: its volume, maxId and tmp
+    folder."""
+    tmp_folder = str(tmp_path / f"tmp_{key}")
+    assert build([WatershedWorkflow(
+        input_path=path, input_key="b", output_path=path, output_key=key,
+        tmp_folder=tmp_folder, config_dir=config_dir, max_jobs=2,
+        target=target, **mask_kw)], raise_on_failure=True)
+    with file_reader(path, "r") as f:
+        return f[key][...], f[key].attrs["maxId"], tmp_folder
+
+
+@pytest.mark.parametrize("case", ["streamed", "ws_2d", "mask", "roi",
+                                  "block_list"])
+def test_final_ids_match_relabel(tmp_path, case):
+    """The single pass that writes final ids (``inline``: one job in
+    block-id order, a running offset) gives the volume and maxId byte for
+    byte that the block offsets and the relabel passes give (``threads``
+    still chains RelabelWorkflow)."""
+    import os
+
+    config_dir, path, mask_kw = _ws_case(tmp_path, case)
+    final, final_max, final_tmp = _run_ws(tmp_path, config_dir, path,
+                                          mask_kw, "inline", "final")
+    relab, relab_max, relab_tmp = _run_ws(tmp_path, config_dir, path,
+                                          mask_kw, "threads", "relabel")
+    assert os.path.exists(os.path.join(relab_tmp, "write_relabel_ws.status"))
+    assert not os.path.exists(
+        os.path.join(final_tmp, "find_uniques_relabel_ws.status"))
+    assert final.dtype == relab.dtype == np.uint64
+    np.testing.assert_array_equal(final, relab)
+    assert final_max == relab_max == len(np.unique(final[final > 0]))
+    # several blocks each with several ids: the offsets were exercised
+    assert final_max > 8
+
+
+def test_final_ids_retry_matches_unbroken_run(tmp_path, monkeypatch):
+    """The single job fails at its third fragment write; the retry over
+    the blocks it did not log starts from the id counts it recorded, and
+    the volume and maxId equal an unbroken run's."""
+    import json
+    import os
+
+    from cluster_tools_tpu.core import storage
+    from cluster_tools_tpu.core.config import ConfigDir
+
+    config_dir, path, mask_kw = _ws_case(tmp_path, "streamed")
+    ConfigDir(config_dir).write_global_config(
+        {"block_shape": [10, 10, 10], "max_num_retries": 1})
+    whole, whole_max, _ = _run_ws(tmp_path, config_dir, path, mask_kw,
+                                  "inline", "whole")
+
+    writes = []
+    setitem = storage.Dataset.__setitem__
+
+    def flaky(self, bb, value):
+        if self.path.endswith("retried"):
+            writes.append(bb)
+            if len(writes) == 3:
+                raise OSError("store write lost")
+        setitem(self, bb, value)
+
+    monkeypatch.setattr(storage.Dataset, "__setitem__", flaky)
+    retried, retried_max, tmp = _run_ws(tmp_path, config_dir, path,
+                                        mask_kw, "inline", "retried")
+    with open(os.path.join(tmp, "watershed.status")) as f:
+        assert json.load(f)["retries"] == 1
+    # 27 blocks written, the third twice
+    assert len(writes) == 28
+    np.testing.assert_array_equal(retried, whole)
+    assert retried_max == whole_max
